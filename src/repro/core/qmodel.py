@@ -189,7 +189,7 @@ def qlinear(ctx: QuantContext, name: str, x: jax.Array, w: jax.Array,
     spec = integer_ops.LinearQuantSpec(
         n_x=mb.n_x, n_w=mb.n_w, n_b=n_b, n_o=mb.n_o, bits=ctx.bits)
     if use_kernel:
-        # Pallas fused kernel when shapes allow; falls back to jnp reference.
+        # the fused Pallas kernel (the jnp reference on CPU, see ops)
         from repro.kernels import ops as kops
         o_int = kops.int8_matmul(x_int, w_int, b_int, spec)
     else:

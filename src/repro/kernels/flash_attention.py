@@ -40,6 +40,11 @@ Two grid variants:
 Tiling follows the ``int8_matmul`` conventions: lane dim 128, fp32 scratch
 persists across the innermost ("arbitrary") KV grid dimension, block shapes
 are static and chosen by the ``ops.py`` wrapper which also pads inputs.
+Head axes are flattened into the lane axis — q/o ``(B, S, H*D)``, K/V
+``(B, S, KVH*D)`` or pool ``(NB, bs, KVH*D)``, all free reshapes of the
+model's layouts — so a block that selects one head is ``(1, rows, D)``:
+its last two dims are (8, 128)-tileable, which a size-1 head dim in the
+second-minor place is not.
 """
 from __future__ import annotations
 
@@ -51,10 +56,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 __all__ = ["make_flash_prefill", "make_flash_decode",
-           "make_paged_flash_decode", "DEFAULT_MASK_VALUE"]
+           "make_paged_flash_decode", "mxu_precision", "DEFAULT_MASK_VALUE"]
 
 # Finite stand-in for -inf: exp(MASK - m) underflows to exactly 0.0 in f32
 # whenever any in-tile entry is live, and never produces inf - inf = NaN.
@@ -65,12 +68,22 @@ DEFAULT_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 _STATS_LANES = 128
 
 
+def mxu_precision(dtype):
+    """Explicit dot precision for a kernel's operands.  Narrow (bf16,
+    int8) operands multiply exactly into the f32/i32 accumulator, so
+    DEFAULT loses nothing, and it must be explicit: under an enclosing
+    ``jax.default_matmul_precision("highest")`` Mosaic would be asked for
+    an fp32 contraction of narrow operands, which it refuses.  f32
+    operands keep the ambient precision (None)."""
+    return jax.lax.Precision.DEFAULT if jnp.dtype(dtype).itemsize < 4 else None
+
+
 def _flash_prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                           *, groups: int, score_scale: float, v_scale: float,
                           causal: bool, q_offset: int, sq: int, skv: int,
                           bq: int, bk: int, nk: int, out_dtype):
-    """Grid (b, h, qi, ki), ki innermost.  Block shapes:
-    q (1,bq,1,dk) · k (1,bk,1,dk) · v (1,bk,1,dv) · o (1,bq,1,dv)."""
+    """Grid (b, h, qi, ki), ki innermost.  Block shapes (head in lanes):
+    q (1,bq,dk) · k (1,bk,dk) · v (1,bk,dv) · o (1,bq,dv)."""
     del groups, sq  # encoded in the index maps / wrapper slicing
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -90,11 +103,12 @@ def _flash_prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :]                          # (bq, dk)
+        q = q_ref[0]                                   # (bq, dk)
         # int8 KV codes cast in-register; exact (|code| < 2^8 << bf16 mantissa)
-        k = k_ref[0, :, 0, :].astype(q.dtype)          # (bk, dk)
+        k = k_ref[0].astype(q.dtype)                   # (bk, dk)
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32) * score_scale
 
         kv_pos = kv_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
@@ -115,16 +129,17 @@ def _flash_prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
             l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
         m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
 
-        v = v_ref[0, :, 0, :].astype(q.dtype)          # (bk, dv)
+        v = v_ref[0].astype(q.dtype)                   # (bk, dv)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(q.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _store():
         l = l_scr[:, :1]
         l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)      # fully-masked rows
-        o_ref[0, :, 0, :] = (acc_scr[...] * l_inv * v_scale).astype(out_dtype)
+        o_ref[0] = (acc_scr[...] * l_inv * v_scale).astype(out_dtype)
 
 
 def make_flash_prefill(b: int, h: int, kvh: int, sq_p: int, skv_p: int,
@@ -134,10 +149,12 @@ def make_flash_prefill(b: int, h: int, kvh: int, sq_p: int, skv_p: int,
                        out_dtype, interpret: bool = False):
     """Build the prefill pallas_call.
 
-    Input layouts match the model's native (B, S, H, D) — the K/V index map
-    contracts the GQA grouping (``h // groups``) so grouped heads read the
-    same KV tile and nothing is repeated in HBM.  ``sq``/``skv`` are the
-    true (unpadded) lengths; ``*_p`` the padded operand shapes.
+    Operands are the model's native (B, S, H, D) with heads flattened into
+    lanes: q (B, Sq_p, H*dk_p), k (B, Skv_p, KVH*dk_p), v (B, Skv_p,
+    KVH*dv_p); output (B, Sq_p, H*dv_p).  The K/V index map contracts the
+    GQA grouping (``h // groups``) so grouped heads read the same KV tile
+    and nothing is repeated in HBM.  ``sq``/``skv`` are the true
+    (unpadded) lengths; ``*_p`` the padded operand shapes.
 
     ``h``/``kvh`` are PER-SHARD counts: under the shard_map wiring
     (DESIGN §8) each device builds this call for its local slice of the
@@ -159,25 +176,25 @@ def make_flash_prefill(b: int, h: int, kvh: int, sq_p: int, skv_p: int,
         kernel,
         grid=(b, h, sq_p // bq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, dk_p),
-                         lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-            pl.BlockSpec((1, bk, 1, dk_p),
-                         lambda b_, h_, qi, ki: (b_, ki, h_ // groups, 0)),
-            pl.BlockSpec((1, bk, 1, dv_p),
-                         lambda b_, h_, qi, ki: (b_, ki, h_ // groups, 0)),
+            pl.BlockSpec((1, bq, dk_p), lambda b_, h_, qi, ki: (b_, qi, h_)),
+            pl.BlockSpec((1, bk, dk_p),
+                         lambda b_, h_, qi, ki: (b_, ki, h_ // groups)),
+            pl.BlockSpec((1, bk, dv_p),
+                         lambda b_, h_, qi, ki: (b_, ki, h_ // groups)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, dv_p),
-                               lambda b_, h_, qi, ki: (b_, qi, h_, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, h, dv_p), out_dtype),
+        out_specs=pl.BlockSpec((1, bq, dv_p),
+                               lambda b_, h_, qi, ki: (b_, qi, h_)),
+        out_shape=jax.ShapeDtypeStruct((b, sq_p, h * dv_p), out_dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, _STATS_LANES), jnp.float32),   # running max m
             pltpu.VMEM((bq, _STATS_LANES), jnp.float32),   # running sum l
             pltpu.VMEM((bq, dv_p), jnp.float32),           # output acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_prefill",
     )
 
 
@@ -202,9 +219,10 @@ def _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki * bk <= pos)
     def _compute():
         q = q_ref[0, 0]                                # (gp, dk)
-        k = k_ref[0, :, 0, :].astype(q.dtype)          # (bk, dk)
+        k = k_ref[0].astype(q.dtype)                   # (bk, dk)
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32) * score_scale
 
         gp = q.shape[0]
@@ -221,9 +239,10 @@ def _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
             l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
         m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
 
-        v = v_ref[0, :, 0, :].astype(q.dtype)
+        v = v_ref[0].astype(q.dtype)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(q.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
@@ -239,11 +258,12 @@ def make_flash_decode(b: int, kvh: int, gp: int, s_max: int, dk_p: int,
     """Build the decode pallas_call.
 
     Operands: pos (1,) int32 scalar-prefetch · q (B, KVH, gp, dk) ·
-    k/v (B, S_max, KVH, d) — the cache's native layout, indexed in place
-    (no transpose, no dequantized copy).  ``gp`` is the GQA group count
-    padded to the sublane minimum.  ``kvh`` is the PER-SHARD KV head count
-    under the shard_map wiring (DESIGN §8); the group structure is
-    shard-invariant, so ``gp`` needs no per-shard adjustment.
+    k/v (B, S_max, KVH*d) — the cache's native layout with heads in
+    lanes, indexed in place (no transpose, no dequantized copy).  ``gp``
+    is the GQA group count padded to the sublane minimum.  ``kvh`` is the
+    PER-SHARD KV head count under the shard_map wiring (DESIGN §8); the
+    group structure is shard-invariant, so ``gp`` needs no per-shard
+    adjustment.
     """
     assert kvh >= 1 and gp >= 1, (
         f"(per-shard) decode needs at least one KV head and one group "
@@ -259,10 +279,10 @@ def make_flash_decode(b: int, kvh: int, gp: int, s_max: int, dk_p: int,
         in_specs=[
             pl.BlockSpec((1, 1, gp, dk_p),
                          lambda b_, h_, ki, pos_ref: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, bk, 1, dk_p),
-                         lambda b_, h_, ki, pos_ref: (b_, ki, h_, 0)),
-            pl.BlockSpec((1, bk, 1, dv_p),
-                         lambda b_, h_, ki, pos_ref: (b_, ki, h_, 0)),
+            pl.BlockSpec((1, bk, dk_p),
+                         lambda b_, h_, ki, pos_ref: (b_, ki, h_)),
+            pl.BlockSpec((1, bk, dv_p),
+                         lambda b_, h_, ki, pos_ref: (b_, ki, h_)),
         ],
         out_specs=pl.BlockSpec((1, 1, gp, dv_p),
                                lambda b_, h_, ki, pos_ref: (b_, h_, 0, 0)),
@@ -276,9 +296,10 @@ def make_flash_decode(b: int, kvh: int, gp: int, s_max: int, dk_p: int,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gp, dv_p), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )
 
 
@@ -306,9 +327,10 @@ def _paged_flash_decode_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ti * bs <= pos)
     def _compute():
         q = q_ref[0, 0]                                # (gp, dk)
-        k = k_ref[0, :, 0, :].astype(q.dtype)          # (bs, dk) pool block
+        k = k_ref[0].astype(q.dtype)                   # (bs, dk) pool block
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32) * score_scale
 
         gp = q.shape[0]
@@ -325,9 +347,10 @@ def _paged_flash_decode_kernel(pos_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
             l_prev * alpha + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
         m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
 
-        v = v_ref[0, :, 0, :].astype(q.dtype)
+        v = v_ref[0].astype(q.dtype)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(q.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32)
 
     @pl.when(ti == nb - 1)
@@ -344,10 +367,11 @@ def make_paged_flash_decode(b: int, kvh: int, gp: int, nbmax: int, bs: int,
     """Build the PAGED decode pallas_call (serving engine, DESIGN §9).
 
     Operands: pos (B,) int32 + block_tables (B, nbmax) int32 (both
-    scalar-prefetch) · q (B, KVH, gp, dk) · k/v POOL (NB, bs, KVH, d) — the
-    block pool's native layout.  ``nbmax`` is the per-sequence block-table
-    width (grid's KV extent), ``bs`` the pool block size; the K/V index
-    maps translate grid step ``ti`` to pool block ``bt[b, ti]``, so the
+    scalar-prefetch) · q (B, KVH, gp, dk) · k/v POOL (NB, bs, KVH*d) — the
+    block pool's native layout with heads in lanes.  ``nbmax`` is the
+    per-sequence block-table width (grid's KV extent), ``bs`` the pool
+    block size; the K/V index maps translate grid step ``ti`` to pool
+    block ``bt[b, ti]``, so the
     kernel walks each slot's logical sequence through physically scattered
     blocks with zero gather/copy.  Unallocated table tail entries point at
     the pool's trash block; their tiles are masked by ``pos`` exactly like
@@ -366,12 +390,12 @@ def make_paged_flash_decode(b: int, kvh: int, gp: int, nbmax: int, bs: int,
         in_specs=[
             pl.BlockSpec((1, 1, gp, dk_p),
                          lambda b_, h_, ti, pos_ref, bt_ref: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, bs, 1, dk_p),
+            pl.BlockSpec((1, bs, dk_p),
                          lambda b_, h_, ti, pos_ref, bt_ref:
-                         (bt_ref[b_, ti], 0, h_, 0)),
-            pl.BlockSpec((1, bs, 1, dv_p),
+                         (bt_ref[b_, ti], 0, h_)),
+            pl.BlockSpec((1, bs, dv_p),
                          lambda b_, h_, ti, pos_ref, bt_ref:
-                         (bt_ref[b_, ti], 0, h_, 0)),
+                         (bt_ref[b_, ti], 0, h_)),
         ],
         out_specs=pl.BlockSpec((1, 1, gp, dv_p),
                                lambda b_, h_, ti, pos_ref, bt_ref:
@@ -386,7 +410,8 @@ def make_paged_flash_decode(b: int, kvh: int, gp: int, nbmax: int, bs: int,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gp, dv_p), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_flash_decode",
     )

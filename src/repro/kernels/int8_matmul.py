@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 __all__ = ["int8_matmul_kernel", "make_int8_matmul"]
 
 
@@ -71,6 +69,9 @@ def int8_matmul_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *,
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        # explicit: an ambient "highest" precision would ask Mosaic for
+        # an fp32 contraction of int8 operands, which it refuses
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32,
     )
 
@@ -126,7 +127,8 @@ def make_int8_matmul(m: int, k: int, n: int, *, bm: int, bk: int, bn: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="int8_matmul",
     )

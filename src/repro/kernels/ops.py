@@ -3,7 +3,8 @@
 Responsibilities: flatten batch dims, pad to tile multiples, pick MXU-aligned
 block shapes that fit VMEM, dispatch to the Pallas kernel (interpret mode on
 CPU), and fall back to the jnp reference for shapes where a kernel launch is
-not worthwhile.
+not worthwhile.  The serving hot path (``ragged_attention``,
+``int8_matmul``) never falls back on an accelerator.
 
 VMEM budget reasoning (v5e: ~128 MiB VMEM/core, we target < 8 MiB per call
 to leave room for double-buffering):
@@ -17,6 +18,7 @@ from __future__ import annotations
 
 import functools
 import math
+import re
 from typing import Optional
 
 import jax
@@ -36,7 +38,7 @@ from repro.kernels.residual_requant import make_residual_requant
 __all__ = ["int8_matmul", "quantize_act", "residual_requant",
            "flash_attention", "flash_decode", "paged_attention",
            "ragged_attention", "attention_kv_bytes", "attn_shard_size",
-           "use_interpret", "DEFAULT_BLOCKS", "FLASH_BLOCKS"]
+           "use_interpret", "tpu_kernels", "DEFAULT_BLOCKS", "FLASH_BLOCKS"]
 
 DEFAULT_BLOCKS = (128, 512, 512)  # (bm, bk, bn)
 FLASH_BLOCKS = (256, 512)         # (bq, bk) — q tile x kv tile
@@ -44,6 +46,24 @@ FLASH_BLOCKS = (256, 512)         # (bq, bk) — q tile x kv tile
 
 def use_interpret() -> bool:
     return jax.default_backend() == "cpu"
+
+
+def tpu_kernels(hlo_text: str) -> set[str]:
+    """Names of the Pallas kernels a compiled TPU program launches — the
+    ``tpu_custom_call`` instructions of its optimized HLO
+    (``compiled.as_text()``), named by each kernel's ``pallas_call``
+    name (``ragged_paged_flash``, ``int8_matmul``, ...)."""
+    return {m.group(1) for line in hlo_text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            for m in [re.search(r'op_name="[^"]*?/(\w+)/pallas_call', line)]
+            if m}
+
+
+def _shard_map(local, mesh: Mesh, in_specs, out_specs):
+    """jit(shard_map(...)) over ``mesh``.  check_vma=False: pallas_call
+    has no varying-manual-axes rule."""
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -55,9 +75,22 @@ def _pad_to(x: jax.Array, mult: int, axis: int) -> jax.Array:
     return jnp.pad(x, pad)
 
 
+def _lane_tile(dim: int, pref: int) -> int:
+    """Largest lane-multiple tile <= ``pref`` that divides ``dim`` — so a
+    weight whose dim is a multiple of 128 but not of ``pref`` (the
+    151936-wide LM head) is never padded, i.e. never copied per call.
+    Dims below 128 take the whole dim (a full-array block is legal)."""
+    if dim <= 128:
+        return dim
+    t = pref
+    while t > 128 and dim % t:
+        t //= 2
+    return t
+
+
 def _pick_blocks(m: int, k: int, n: int) -> tuple[int, int, int]:
     bm, bk, bn = DEFAULT_BLOCKS
-    return min(bm, m), min(bk, k), min(bn, n)
+    return (min(bm, _round_up(m, 8)), _lane_tile(k, bk), _lane_tile(n, bn))
 
 
 def int8_matmul(x_int: jax.Array, w_int: jax.Array,
@@ -68,14 +101,14 @@ def int8_matmul(x_int: jax.Array, w_int: jax.Array,
     The batched/ragged entry point for the W8A8 forward (DESIGN §13):
     leading dims — a (B, S) batch or a packed ragged (T,) token stream —
     are flattened into the M axis.  Static shift constants come from
-    ``spec`` (deploy artifacts).  Shapes not worth a kernel launch (tiny
-    K, N or M — e.g. non-MXU-aligned head/model dims) use the jnp
-    reference ``int_linear`` — same bit-exact contract, so the fallback
-    is invisible to the parity rig.  On CPU the reference also serves
-    MXU-aligned shapes by default: interpret-mode Pallas simulates the
-    grid serially and would dominate the serving step for zero fidelity
-    gain.  ``force_kernel=True`` overrides that policy so kernel parity
-    tests exercise the fused epilogue itself (in interpret mode on CPU).
+    ``spec`` (deploy artifacts).  On CPU the jnp reference ``int_linear``
+    (same bit-exact contract) serves every shape by default:
+    interpret-mode Pallas simulates the grid serially and would dominate
+    the serving step for zero fidelity gain.
+    ``force_kernel=True`` overrides that policy so kernel parity tests
+    exercise the fused epilogue itself (in interpret mode on CPU).  On an
+    accelerator every shape launches the kernel: rows pad to the sublane
+    tile, and dims below a lane take full-dim blocks.
     """
     *batch, k = x_int.shape
     n = w_int.shape[-1]
@@ -86,9 +119,8 @@ def int8_matmul(x_int: jax.Array, w_int: jax.Array,
     lo, hi = ((0, (1 << spec.bits) - 1) if unsigned
               else (-(1 << (spec.bits - 1)), (1 << (spec.bits - 1)) - 1))
 
-    if m < 16 or k < 128 or n < 128 or (use_interpret() and not force_kernel):
-        out = int_linear(x_int, w_int, b_int, spec, apply_relu=relu)
-        return out
+    if use_interpret() and not force_kernel:
+        return int_linear(x_int, w_int, b_int, spec, apply_relu=relu)
 
     x2 = x_int.reshape(m, k)
     bm, bk, bn = _pick_blocks(m, k, n)
@@ -163,16 +195,13 @@ def _make_sharded_prefill(mesh: Mesh, head_entry, bdim, causal: bool,
     power-of-two KV scale folded into that shard's kernel constants.  No
     collectives — softmax is over the (replicated) KV sequence, so shards
     are independent."""
-    from jax.experimental.shard_map import shard_map
     spec = P(bdim, None, head_entry, None)
 
     def local(q, k, v):
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                                kv_frac_bits=kv_frac_bits, scale=scale)
 
-    # check_rep=False: pallas_call has no replication rule
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec, check_rep=False))
+    return _shard_map(local, mesh, (spec, spec, spec), spec)
 
 
 @functools.lru_cache(maxsize=64)
@@ -182,16 +211,13 @@ def _make_sharded_decode(mesh: Mesh, head_entry, bdim, kv_frac_bits,
     codes + their static scale per shard), q is resharded to match (tiny),
     ``pos`` is replicated.  Grouped query heads of a KV head land on the
     same shard, so the kernel's one-DMA-per-group contract holds."""
-    from jax.experimental.shard_map import shard_map
     spec = P(bdim, None, head_entry, None)
 
     def local(pos, q, k, v):
         return flash_decode(q, k, v, pos=pos, kv_frac_bits=kv_frac_bits,
                             scale=scale)
 
-    return jax.jit(shard_map(local, mesh=mesh,
-                             in_specs=(P(), spec, spec, spec),
-                             out_specs=spec, check_rep=False))
+    return _shard_map(local, mesh, (P(), spec, spec, spec), spec)
 
 
 def _resolve_kv_frac_bits(k: jax.Array, kv_frac_bits: Optional[int]) -> int:
@@ -268,15 +294,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     dk_p, dv_p = _round_up(dk, 128), _round_up(dv, 128)
 
     def kernel_call(q_, k_, v_):
-        qp = _pad_to(_pad_to(q_, bq, 1), dk_p, 3)
-        kp = _pad_to(_pad_to(k_, bk, 1), dk_p, 3)
-        vp = _pad_to(_pad_to(v_, bk, 1), dv_p, 3)
+        # heads flatten into the lane axis (free reshapes; kernel layout)
+        qp = _pad_to(_pad_to(q_, bq, 1), dk_p, 3).reshape(b, sq_p, h * dk_p)
+        kp = _pad_to(_pad_to(k_, bk, 1), dk_p, 3).reshape(
+            b, skv_p, kvh * dk_p)
+        vp = _pad_to(_pad_to(v_, bk, 1), dv_p, 3).reshape(
+            b, skv_p, kvh * dv_p)
         call = make_flash_prefill(
             b, h, kvh, sq_p, skv_p, dk_p, dv_p, bq=bq, bk=bk, causal=causal,
             q_offset=q_offset, sq=sq, skv=skv,
             score_scale=scale * 2.0 ** (-nkv), v_scale=2.0 ** (-nkv),
             k_dtype=k_.dtype, out_dtype=q_.dtype, interpret=use_interpret())
-        return call(qp, kp, vp)[:, :sq, :, :dv]
+        out = call(qp, kp, vp).reshape(b, sq_p, h, dv_p)
+        return out[:, :sq, :, :dv]
 
     if int8_kv:
         # inference-only dataflow (codes are non-differentiable anyway)
@@ -355,7 +385,8 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
         score_scale=scale * 2.0 ** (-nkv), v_scale=2.0 ** (-nkv),
         out_dtype=q.dtype, interpret=use_interpret())
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
-    out = call(pos_arr, q4, k, v)                      # (B, KVH, gp, dv)
+    out = call(pos_arr, q4, k.reshape(b, s_max, kvh * dk),
+               v.reshape(b, s_max, kvh * dv))          # (B, KVH, gp, dv)
     return out[:, :, :groups].reshape(b, 1, h, dv)
 
 
@@ -407,7 +438,6 @@ def _make_sharded_paged_decode(mesh: Mesh, head_entry, bdim, kv_frac_bits,
     are slot-metadata — they follow the q/batch partition (``bdim``) and
     are replicated over the tensor axis, so every head shard walks the
     same logical→physical block mapping.  No collectives."""
-    from jax.experimental.shard_map import shard_map
     qspec = P(bdim, None, head_entry, None)
     pspec = P(None, None, head_entry, None)
 
@@ -415,10 +445,8 @@ def _make_sharded_paged_decode(mesh: Mesh, head_entry, bdim, kv_frac_bits,
         return paged_attention(q, kp, vp, bt, pos[:, None],
                                kv_frac_bits=kv_frac_bits, scale=scale)
 
-    return jax.jit(shard_map(
-        local, mesh=mesh,
-        in_specs=(P(bdim), P(bdim, None), qspec, pspec, pspec),
-        out_specs=qspec, check_rep=False))
+    return _shard_map(local, mesh,
+                      (P(bdim), P(bdim, None), qspec, pspec, pspec), qspec)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -478,7 +506,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         score_scale=scale * 2.0 ** (-nkv), v_scale=2.0 ** (-nkv),
         out_dtype=q.dtype, interpret=use_interpret())
     pos = jnp.asarray(q_positions[:, 0], jnp.int32)
-    out = call(pos, jnp.asarray(block_tables, jnp.int32), q4, k_pool, v_pool)
+    nb = k_pool.shape[0]
+    out = call(pos, jnp.asarray(block_tables, jnp.int32), q4,
+               k_pool.reshape(nb, bs, kvh * dk),
+               v_pool.reshape(nb, bs, kvh * dv))
     return out[:, :, :groups].reshape(b, 1, h, dv)
 
 
@@ -491,7 +522,6 @@ def _make_sharded_ragged(mesh: Mesh, head_entry, kv_frac_bits, scale,
     NOT partitioned — a ragged stream has no slot-aligned batch dim for
     the data axes to split, and T_pad is a few dozen rows, so replicating
     it across data-parallel shards is the cheap and correct layout."""
-    from jax.experimental.shard_map import shard_map
     qspec = P(None, head_entry, None)
     pspec = P(None, None, head_entry, None)
 
@@ -500,10 +530,9 @@ def _make_sharded_ragged(mesh: Mesh, head_entry, kv_frac_bits, scale,
                                 kv_frac_bits=kv_frac_bits, scale=scale,
                                 tq_max=tq_max)
 
-    return jax.jit(shard_map(
-        local, mesh=mesh,
-        in_specs=(qspec, pspec, pspec, P(None, None), P(), P(), P()),
-        out_specs=qspec, check_rep=False))
+    return _shard_map(
+        local, mesh,
+        (qspec, pspec, pspec, P(None, None), P(), P(), P()), qspec)
 
 
 def ragged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -530,45 +559,49 @@ def ragged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     block walk happens in the DMA engine, int8 codes dequantize
     in-register.  ``tq_max`` (static) bounds the per-sequence q_len so
     the kernel's q window stays narrow; None means the whole stream
-    width.  Other shapes take the gather oracle
-    (``ref.ragged_attention_ref``), which is also the CPU engine path.
-    With a multi-device ``mesh``, KV heads shard over ``shard_axis``
-    (whole GQA groups — §8) and descriptors replicate.
+    width.  On the CPU (interpret mode) other shapes take the gather
+    oracle (``ref.ragged_attention_ref``); on an accelerator they raise
+    — the serving hot path never silently leaves the kernel.  With a
+    multi-device ``mesh``, KV heads shard over ``shard_axis`` (whole GQA
+    groups — §8) and descriptors replicate.
     """
     t, h, dk = q.shape
-    bs, kvh = k_pool.shape[1], k_pool.shape[2]
+    nb, bs, kvh = k_pool.shape[:3]
     dv = v_pool.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
     nkv = _resolve_kv_frac_bits(k_pool, kv_frac_bits)
     kernel_ok = bs % 128 == 0 and dk % 128 == 0 and dv % 128 == 0
+    if not kernel_ok and not use_interpret():
+        raise ValueError(
+            f"ragged attention kernel needs 128-multiple block size and "
+            f"head dims (got block_size={bs}, dk={dk}, dv={dv}); the "
+            f"gather reference is a CPU-only oracle")
     bt = jnp.asarray(block_tables, jnp.int32)
     qs = jnp.asarray(q_start, jnp.int32)
     ql = jnp.asarray(q_len, jnp.int32)
     kl = jnp.asarray(kv_len, jnp.int32)
-    if mesh is not None and mesh.size > 1:
+    sharded = mesh is not None and mesh.size > 1
+    if sharded:
         tp = attn_shard_size(mesh, shard_axis)
         _check_head_divisibility(kvh, tp, shard_axis)
-        if not kernel_ok:
-            # reference path is plain jnp — GSPMD partitions it directly
-            return ref.ragged_attention_ref(
-                q, k_pool, v_pool, bt, qs, ql, kl,
-                kv_frac_bits=kv_frac_bits, scale=scale)
-        call = _make_sharded_ragged(mesh, shard_axis if tp > 1 else None,
-                                    kv_frac_bits, scale, tq_max)
-        return call(q, k_pool, v_pool, bt, qs, ql, kl)
     if not kernel_ok:
+        # plain jnp — under a mesh GSPMD partitions it directly
         return ref.ragged_attention_ref(
             q, k_pool, v_pool, bt, qs, ql, kl,
             kv_frac_bits=kv_frac_bits, scale=scale)
+    if sharded:
+        call = _make_sharded_ragged(mesh, shard_axis if tp > 1 else None,
+                                    kv_frac_bits, scale, tq_max)
+        return call(q, k_pool, v_pool, bt, qs, ql, kl)
     t_pad = _round_up(t, 8)
-    tq = _round_up(min(tq_max, t) if tq_max else t, 8)
-    tq = min(tq, t_pad)
-    qp = _pad_to(q, 8, 0)
+    tq = min(tq_max, t) if tq_max else t
+    qp = _pad_to(q, 8, 0).reshape(t_pad, h * dk)
     call = make_ragged_paged_flash(
         bt.shape[0], h, kvh, bt.shape[1], bs, t_pad, tq, dk, dv,
         score_scale=scale * 2.0 ** (-nkv), v_scale=2.0 ** (-nkv),
         out_dtype=q.dtype, interpret=use_interpret())
-    out = call(qs, ql, kl, bt, qp, k_pool, v_pool)     # (T_pad, H, dv)
+    out = call(qs, ql, kl, bt, qp, k_pool.reshape(nb, bs, kvh * dk),
+               v_pool.reshape(nb, bs, kvh * dv)).reshape(t_pad, h, dv)
     # rows covered by no descriptor were never written by the kernel —
     # pin them to the contract's zero
     _, valid, _ = ref.ragged_token_meta(qs, ql, kl, t)

@@ -21,7 +21,9 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +34,29 @@ from repro.core.lm_calibrate import calibrate_lm
 from repro.core.qmodel import QuantContext, QuantMode
 from repro.data import SyntheticLMStream
 from repro.launch import steps as S
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
+
+
+# KV pool block size, tokens: the ragged attention kernel takes multiples
+# of 128 (and on an accelerator refuses anything else)
+DEFAULT_BLOCK_SIZE = 128
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honoured as is (JAX reads
+    it itself; nothing is set here).  Otherwise the cache lives at the
+    fixed path ``<repo>/.jax_cache`` — fixed because the path is part of
+    the cache key.  Called from entry points only, never at import and
+    never from tests.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _resolve_cfg_mesh(arch: str, *, smoke: bool,
@@ -57,7 +81,7 @@ def _resolve_cfg_mesh(arch: str, *, smoke: bool,
         cfg = dataclasses.replace(cfg, **cfg_overrides)
     mesh = None
     if mesh_shape is not None:
-        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_mesh(mesh_shape)
     return cfg, mesh
 
 
@@ -164,7 +188,8 @@ def poisson_workload(vocab_size: int, *, n_requests: int, rate: float,
 
 
 def serve_engine(arch: str, *, n_requests: int = 16, rate: float = 50.0,
-                 n_slots: int = 4, block_size: int = 16, chunk: int = 16,
+                 n_slots: int = 4, block_size: int = DEFAULT_BLOCK_SIZE,
+                 chunk: int = 16,
                  max_model_len: int | None = None,
                  num_blocks: int | None = None, mode: str = "fp",
                  calibrate: bool = False, smoke: bool = True, seed: int = 0,
@@ -339,8 +364,9 @@ def main(argv=None):
                     help="[--engine] Poisson arrival rate, req/s")
     ap.add_argument("--slots", type=int, default=4,
                     help="[--engine] continuous-batch width")
-    ap.add_argument("--block-size", type=int, default=16,
-                    help="[--engine] KV pool block size, tokens")
+    ap.add_argument("--block-size", type=int, default=DEFAULT_BLOCK_SIZE,
+                    help="[--engine] KV pool block size, tokens (the "
+                         "ragged kernel takes multiples of 128)")
     ap.add_argument("--chunk", type=int, default=16,
                     help="[--engine] prefill chunk / per-step token budget")
     ap.add_argument("--temperature", type=float, default=0.0,
@@ -433,6 +459,7 @@ def main(argv=None):
                          "dispatch (DESIGN §12) — A/B baseline for "
                          "padding waste and compile count")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     mesh_shape = None
     if args.mesh is not None:
         d, m = (int(x) for x in args.mesh.lower().split("x"))
@@ -455,7 +482,7 @@ def main(argv=None):
         # archs that grow, where the record always carries real values.
         out = serve_engine(args.arch, requests=build_requests(rec),
                            n_slots=es["n_slots"],
-                           block_size=es["block_size"] or 16,
+                           block_size=es["block_size"] or DEFAULT_BLOCK_SIZE,
                            chunk=es["chunk"],
                            max_model_len=es["max_model_len"],
                            num_blocks=es["num_blocks"], mode=args.mode,

@@ -20,6 +20,7 @@ fallback.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -57,7 +58,12 @@ class PagedKVCache(NamedTuple):
     v: jax.Array        # (NB, BS, KVH, D)
 
 
-class RaggedBatch(NamedTuple):
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["dest", "block_tables", "q_start", "q_len",
+                                "kv_len"],
+                   meta_fields=["tq_max"])
+@dataclasses.dataclass(frozen=True)
+class RaggedBatch:
     """One MIXED serving step as a flattened token stream (DESIGN §12).
 
     Prefill chunks, decode rows, and speculative tails of every live slot
@@ -68,13 +74,17 @@ class RaggedBatch(NamedTuple):
     token's KV codes scatter to — padding rows point at the trash block.
     All arrays are int32; descriptors follow the contract in
     ``kernels.ragged_flash`` (q_start nondecreasing, windows disjoint,
-    padding slots zeroed with trash-block tables).
+    padding slots zeroed with trash-block tables).  ``tq_max`` is STATIC
+    (part of the pytree structure, so jit specializes on it): the bound
+    on any sequence's ``q_len`` that sizes the kernel's per-sequence q
+    window — None means the whole stream.
     """
     dest: jax.Array          # (T,)       flattened pool row per token
     block_tables: jax.Array  # (S, NBmax) logical block -> pool block
     q_start: jax.Array       # (S,)
     q_len: jax.Array         # (S,)
     kv_len: jax.Array        # (S,)
+    tq_max: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
@@ -319,7 +329,8 @@ def gqa_attention(ctx: QuantContext, p: dict, x: jax.Array, cfg: ModelConfig,
         out = kops.ragged_attention(
             q[0], k_pool, v_pool, ragged.block_tables, ragged.q_start,
             ragged.q_len, ragged.kv_len, kv_frac_bits=kv_frac_bits,
-            mesh=current_mesh(), shard_axis=cfg.attn_shard_axis)[None]
+            tq_max=ragged.tq_max, mesh=current_mesh(),
+            shard_axis=cfg.attn_shard_axis)[None]
         out = constrain(out.reshape(b, s, h * hd), ("batch", None, "heads"))
         return (linear(ctx, f"{name}/wo", out, p["wo"]),
                 PagedKVCache(k_pool, v_pool))

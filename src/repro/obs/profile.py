@@ -124,6 +124,9 @@ class Profiler:
         self.profile_dir = profile_dir
         self.cost = cost
         self.shape_costs: dict[Any, dict] = {}
+        # the lowered executable of each costed shape, so callers can
+        # compile it and read what ran (``.compile().as_text()``)
+        self.lowered: dict[Any, Any] = {}
 
     @property
     def enabled(self) -> bool:
@@ -159,8 +162,9 @@ class Profiler:
         Uses AOT ``lower(...).cost_analysis()`` (no compile, no
         execute — safe to call before the real dispatch donates its
         buffers); falls back to ``.compile().cost_analysis()`` on older
-        jax.  Returns {flops, bytes_accessed} (floats, -1.0 when the
-        backend reports nothing) or None when cost analysis is off."""
+        jax.  The ``Lowered`` is kept in ``self.lowered[shape_key]``.
+        Returns {flops, bytes_accessed} (floats, -1.0 when the backend
+        reports nothing) or None when cost analysis is off."""
         if not self.cost:
             return None
         hit = self.shape_costs.get(shape_key)
@@ -168,7 +172,7 @@ class Profiler:
             return hit
         entry = {"flops": -1.0, "bytes_accessed": -1.0}
         try:
-            lowered = jitfn.lower(*args)
+            lowered = self.lowered[shape_key] = jitfn.lower(*args)
             try:
                 ca = lowered.cost_analysis()
             except Exception:
@@ -199,3 +203,4 @@ class Profiler:
 
     def reset(self) -> None:
         self.shape_costs.clear()
+        self.lowered.clear()

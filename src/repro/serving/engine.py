@@ -380,6 +380,9 @@ class ServingEngine:
         # jit sees O(log) distinct ragged executables
         budget = self.sched.prefill_token_budget
         self._t_max = max(8, -(-(budget + n_slots * kp1) // 8) * 8)
+        # no sequence feeds more than one chunk or one verify tail per
+        # step: the static bound that sizes the kernel's q window
+        self._tq_max = max(self.sched.chunk, kp1)
 
         # COW device copy (DESIGN §10): duplicate one pool block's rows
         # (all layers, K and V) into a fresh private block before a write
@@ -879,7 +882,7 @@ class ServingEngine:
         rb = RaggedBatch(
             dest=jnp.asarray(dest), block_tables=jnp.asarray(bt),
             q_start=jnp.asarray(q_start), q_len=jnp.asarray(q_len),
-            kv_len=jnp.asarray(kv_len))
+            kv_len=jnp.asarray(kv_len), tq_max=self._tq_max)
         shape_key = ("ragged", len(tokens), len(temps))
         first_call = shape_key not in self._step_times
         args = (self.params, jnp.asarray(tokens), self.cache,

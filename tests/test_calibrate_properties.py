@@ -1,8 +1,6 @@
 """Property tests for Algorithm-1 calibration (Eq. 5/6, DESIGN §3/§13).
 
-Runs under real hypothesis when installed, else the deterministic
-sampled-sweep shim in ``tests/_hyp_stub.py`` (the tier-1 container ships
-no hypothesis).  Properties:
+Runs under hypothesis.  Properties:
 
   * the chosen (N_w, N_b, N_o) always lie inside the Eq.-6 narrowed
     windows ``[N^max - tau, N^max]`` of their tensors;
@@ -16,10 +14,7 @@ no hypothesis).  Properties:
 import numpy as np
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                    # tier-1 container
-    from tests._hyp_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.calibrate import calibrate_linear_module
 from repro.core.lm_calibrate import calibrate_lm

@@ -6,10 +6,7 @@ tiling/epilogue logic is what's validated; MXU lowering is the TPU target).
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                     # container lacks hypothesis
-    from _hyp_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.integer_ops import LinearQuantSpec
 from repro.kernels import ops, ref

@@ -10,9 +10,9 @@ while resident).
 """
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.kv_pool import TRASH_BLOCK, BlockPool, BlockPoolError
-from tests._hyp_stub import given, settings, st
 
 
 @settings(max_examples=25, deadline=None)

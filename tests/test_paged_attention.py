@@ -17,6 +17,7 @@ import pytest
 
 from repro.core.qscheme import dequant, quant
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models.attention import _repeat_kv, chunked_attention
 
 NKV = 4
@@ -119,7 +120,7 @@ def test_paged_decode_sharded_parity(groups, mesh_shape):
     oracle exactly like the dense flash path does (DESIGN §8/§9)."""
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices (tests/conftest.py forces them)")
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     q, kp, vp, bt, qf, kd, vd, nkv = _build_pool(9, 4, groups, "int8")
     pos = jnp.asarray(np.asarray(POS, np.int32))[:, None]
     out = ops.paged_attention(q, kp, vp, bt, pos, kv_frac_bits=nkv,
@@ -139,7 +140,7 @@ def test_paged_non_dividing_heads_raise():
     axis that would split a GQA group is refused at the ops level."""
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices")
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     q, kp, vp, bt, *_ , nkv = _build_pool(13, 2, 1, "int8")
     pos = jnp.asarray(np.asarray(POS, np.int32))[:, None]
     with pytest.raises(NotImplementedError, match=r"KV head count \(2\)"):
@@ -155,7 +156,7 @@ def test_paged_pool_sharding_rule_head_sharded():
     from repro.launch import steps as S
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices")
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     cfg = dc.replace(get_smoke_config("qwen3_1_7b"), kv_cache_bits=8)
     abs_pool = S.abstract_paged_cache(cfg, num_blocks=9, block_size=16)
     spec = shd.cache_sharding_rules(abs_pool, mesh, attn_kernel="flash")

@@ -15,10 +15,10 @@ alloc/share/divert(COW)/extend/free/evict:
 """
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.kv_pool import TRASH_BLOCK, BlockPool, BlockPoolError
 from repro.serving.prefix_cache import ROOT_KEY, block_key
-from tests._hyp_stub import given, settings, st
 
 BS = 4
 

@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                     # container lacks hypothesis
-    from _hyp_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import qscheme as Q
 

@@ -1,8 +1,6 @@
 """Property-based round-trip invariants for the Eq. 1 po2 scheme.
 
-Runs under real hypothesis when installed, else the deterministic sampled
-fallback in ``_hyp_stub`` (seeded rng — failures reproduce).  These lock
-in permanently:
+Runs under hypothesis.  These lock in permanently:
 
 * quantize -> dequantize IDEMPOTENCE: the po2 grid is a fixed point of
   Eq. 1, so a second pass through the quantizer changes nothing;
@@ -19,10 +17,7 @@ in permanently:
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                     # container lacks hypothesis
-    from _hyp_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import qscheme as Q
 from repro.core.integer_ops import LinearQuantSpec, int_linear

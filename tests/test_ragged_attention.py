@@ -20,6 +20,7 @@ import pytest
 from repro.core.qscheme import dequant, quant
 from repro.kernels import ops
 from repro.kernels.ref import ragged_attention_ref
+from repro.launch.mesh import make_mesh
 from repro.models.attention import _repeat_kv, chunked_attention
 
 NKV = 4
@@ -144,6 +145,16 @@ def test_ragged_fallback_small_dims(kv):
     _check_vs_dense(out, qf, kd, vd, 2, items, kv)
 
 
+def test_ragged_small_dims_raise_off_cpu(monkeypatch):
+    """Off the CPU the gather reference is never a silent stand-in: a
+    shape the kernel cannot take raises."""
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    (q, kp, vp, bt, qs, ql, kl, nkv, *_rest) = _build_mixed(
+        5, 2, 2, "int8", bs=16, smax=64, dk=16, items=((8, 40), (1, 33)))
+    with pytest.raises(ValueError, match="block_size=16, dk=16"):
+        ops.ragged_attention(q, kp, vp, bt, qs, ql, kl, kv_frac_bits=nkv)
+
+
 @pytest.mark.parametrize("mesh_shape", [(2, 2), (1, 4)])
 @pytest.mark.parametrize("groups", [1, 4])
 def test_ragged_sharded_parity(groups, mesh_shape):
@@ -152,7 +163,7 @@ def test_ragged_sharded_parity(groups, mesh_shape):
     the single-device dense oracle."""
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices (tests/conftest.py forces them)")
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     (q, kp, vp, bt, qs, ql, kl, nkv, qf, kd, vd) = \
         _build_mixed(9, 4, groups, "int8")
     out = ops.ragged_attention(q, kp, vp, bt, qs, ql, kl,
@@ -165,7 +176,7 @@ def test_ragged_non_dividing_heads_raise():
     group is refused at the ops level, like every other flash kernel."""
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices")
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     (q, kp, vp, bt, qs, ql, kl, nkv, *_rest) = _build_mixed(11, 2, 1, "int8")
     with pytest.raises(NotImplementedError, match=r"KV head count \(2\)"):
         ops.ragged_attention(q, kp, vp, bt, qs, ql, kl,

@@ -11,11 +11,11 @@ throughout.
 """
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.kv_pool import BlockPool
 from repro.serving.scheduler import (Request, RequestState, Scheduler,
                                      chunk_bucket)
-from tests._hyp_stub import given, settings, st
 
 MAX_LEN = 32
 

@@ -28,6 +28,7 @@ import pytest
 
 from repro.core.qscheme import dequant, quant
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models.attention import _repeat_kv, chunked_attention
 
 NKV = 4                # Eq.-1 fractional bits for the int8 KV grid
@@ -41,7 +42,7 @@ def _mesh(name):
     d, m = MESHES[name]
     if jax.device_count() < d * m:
         pytest.skip(f"needs {d * m} devices, have {jax.device_count()}")
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
 
 
 def _make_qkv(seed, groups, kv):
@@ -219,7 +220,7 @@ def test_end_to_end_sharded_flash_decode():
                                               head_dim=128),
         kv_cache_bits=8)                          # n_heads=4, n_kv_heads=2
     cfg8f = dataclasses.replace(cfg8, attn_kernel="flash")
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     params = M.init_params(cfg8, jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 121), 0,
                               cfg8.vocab_size)

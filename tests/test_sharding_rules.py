@@ -6,19 +6,11 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.distributed import sharding as shd
 from repro.launch import steps as S
-
-
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: 0.4.x takes ((name, size), ...);
-    newer releases take (sizes, names)."""
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
+from repro.launch.mesh import make_mesh
 
 
 def _mesh(data=2, model=2):
-    return _abstract_mesh((data, model), ("data", "model"))
+    return AbstractMesh((data, model), ("data", "model"))
 
 
 def test_attention_and_mlp_rules():
@@ -64,7 +56,7 @@ def test_constrain_noop_outside_scope():
 
 
 def test_constrain_inside_scope_single_device():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with shd.activation_sharding(mesh):
         x = jnp.zeros((3, 5))
         y = shd.constrain(x, ("batch", "model"))
@@ -84,7 +76,7 @@ def test_cache_sharding_rules():
 
 
 def test_batch_sharding_composite_axis():
-    multi = _abstract_mesh((2, 4, 4), ("pod", "data", "model"))
+    multi = AbstractMesh((2, 4, 4), ("pod", "data", "model"))
     assert shd.batch_sharding(multi, 2) == P(("pod", "data"), None)
 
 

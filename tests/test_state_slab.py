@@ -1,8 +1,6 @@
 """Property tests for the fixed-slab recurrent-state substrate (§16).
 
-Runs under real hypothesis when installed, else the deterministic sampled
-fallback in ``_hyp_stub`` (seeded rng — failures reproduce).  Locked in
-permanently:
+Runs under hypothesis.  Locked in permanently:
 
 * the slab-pool partition invariant — after ANY random interleaving of
   alloc / free / evict, {free} ∪ {live} exactly covers the non-trash
@@ -23,10 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                     # container lacks hypothesis
-    from _hyp_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import qscheme as Q
 from repro.serving import (BlockPoolError, StateSlabPool, TRASH_SLAB,

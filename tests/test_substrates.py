@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.data import ShardedLoader, SyntheticLMStream
+from repro.launch.mesh import make_mesh
 from repro.optim import (adamw, adafactor, clip_by_global_norm,
                          warmup_cosine, quantize_grads_po2,
                          dequantize_grads_po2)
@@ -82,16 +83,14 @@ def test_grad_compression_roundtrip():
 
 def test_compressed_psum_single_device():
     from repro.optim.compression import compressed_psum
-    from jax.sharding import Mesh
-    import jax.experimental.shard_map as shard_map
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(size=(32,)) * 0.1,
                           jnp.float32)}
 
     def f(g):
         return compressed_psum(g, "d")
 
-    out = jax.jit(shard_map.shard_map(
+    out = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec(),),
         out_specs=jax.sharding.PartitionSpec()))(g)
     rel = float(jnp.linalg.norm(out["w"] - g["w"]) /

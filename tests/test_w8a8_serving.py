@@ -30,6 +30,7 @@ from repro.core.lm_calibrate import calibrate_lm
 from repro.core.qmodel import (QuantContext, QuantMode, qlinear,
                                quantize_params)
 from repro.core.qscheme import dequant, fake_quant, round_half_away
+from repro.launch.mesh import make_mesh
 from repro.models import common as common_lib
 from repro.models import model as M
 from repro.models import transformer as tfm
@@ -334,7 +335,7 @@ def test_engine_w8a8_shard_map_4dev(cal):
     qp = quantize_params(params, ctx)
     reqs = _workload(rng, 3, cfg_w8.vocab_size)
     eng_1, _ = _run(cfg_w8, qp, ctx, reqs)
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     eng_4, rep_4 = _run(cfg_w8, qp, ctx, reqs, mesh=mesh)
     assert rep_4["completed"] == len(reqs)
     for r in reqs:
