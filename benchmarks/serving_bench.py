@@ -769,6 +769,13 @@ def bench_obs(*, seed: int = 0, artifacts: str | None = None) -> dict:
         if tr is not None and tr.enabled:      # pragma: no cover
             raise AssertionError
     guard_s = (time.perf_counter() - t0) / n_iter
+    # a phase site (Tracer.phase) costs a call and an empty with-block
+    # when off; every site is charged the dearer of the two patterns
+    t0 = time.perf_counter()
+    for _ in range(n_iter):
+        with tr.phase("engine.plan", "engine"):
+            pass
+    guard_s = max(guard_s, (time.perf_counter() - t0) / n_iter)
     # measured guard evaluations per step: every ring event and every
     # per-token mark of the ENABLED twin evaluated the same guard on the
     # disabled engine; x2 for sites whose guard ran but emitted nothing

@@ -286,25 +286,27 @@ def gqa_attention(ctx: QuantContext, p: dict, x: jax.Array, cfg: ModelConfig,
     h, kvh = cfg.n_heads, cfg.n_kv_heads
     src = x if kv_x is None else kv_x
 
-    q = linear(ctx, f"{name}/wq", x, p["wq"], p.get("bq"))
-    k = linear(ctx, f"{name}/wk", src, p["wk"], p.get("bk"))
-    # wo's input grid is THREADED from wv's output grid (DESIGN §13,
-    # lm_calibrate.DATAFLOW_CHAIN): attention rows are softmax-convex
-    # combinations of V rows, so the wo input lives inside wv's range.
-    v = linear(ctx, f"{name}/wv", src, p["wv"], p.get("bv"))
-    q = constrain(q.reshape(b, s, h, hd), ("batch", None, "heads", None))
-    k = constrain(k.reshape(b, src.shape[1], kvh, hd),
-                  ("batch", None, "heads", None))
-    v = constrain(v.reshape(b, src.shape[1], kvh, hd),
-                  ("batch", None, "heads", None))
+    with jax.named_scope("attn_qkv"):
+        q = linear(ctx, f"{name}/wq", x, p["wq"], p.get("bq"))
+        k = linear(ctx, f"{name}/wk", src, p["wk"], p.get("bk"))
+        # wo's input grid is THREADED from wv's output grid (DESIGN §13,
+        # lm_calibrate.DATAFLOW_CHAIN): attention rows are softmax-convex
+        # combinations of V rows, so the wo input lives inside wv's range.
+        v = linear(ctx, f"{name}/wv", src, p["wv"], p.get("bv"))
+        q = constrain(q.reshape(b, s, h, hd), ("batch", None, "heads", None))
+        k = constrain(k.reshape(b, src.shape[1], kvh, hd),
+                      ("batch", None, "heads", None))
+        v = constrain(v.reshape(b, src.shape[1], kvh, hd),
+                      ("batch", None, "heads", None))
 
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        kv_positions = positions if kv_x is None else jnp.arange(src.shape[1])[None]
-        k = apply_rope(k, kv_positions, cfg.rope_theta)
+        if cfg.qk_norm:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        if use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            kv_positions = positions if kv_x is None \
+                else jnp.arange(src.shape[1])[None]
+            k = apply_rope(k, kv_positions, cfg.rope_theta)
 
     if isinstance(cache, PagedKVCache) and ragged is not None:
         # unified ragged path (DESIGN §12): the batch IS one flattened
@@ -315,25 +317,32 @@ def gqa_attention(ctx: QuantContext, p: dict, x: jax.Array, cfg: ModelConfig,
         assert b == 1, "ragged serving flattens the batch to (1, T)"
         nb_pool, bs_blk = cache.k.shape[0], cache.k.shape[1]
         kv_frac_bits = None
-        if cache.k.dtype == jnp.int8:
-            from repro.core.qscheme import quant
-            kv_frac_bits = cfg.kv_cache_frac_bits
-            k_c, v_c = quant(k, kv_frac_bits, 8), quant(v, kv_frac_bits, 8)
-        else:
-            k_c, v_c = k.astype(cache.k.dtype), v.astype(cache.v.dtype)
-        k_pool = cache.k.reshape(nb_pool * bs_blk, kvh, hd).at[
-            ragged.dest].set(k_c.reshape(-1, kvh, hd)).reshape(cache.k.shape)
-        v_pool = cache.v.reshape(nb_pool * bs_blk, kvh, hd).at[
-            ragged.dest].set(v_c.reshape(-1, kvh, hd)).reshape(cache.v.shape)
+        with jax.named_scope("kv_write"):
+            if cache.k.dtype == jnp.int8:
+                from repro.core.qscheme import quant
+                kv_frac_bits = cfg.kv_cache_frac_bits
+                k_c = quant(k, kv_frac_bits, 8)
+                v_c = quant(v, kv_frac_bits, 8)
+            else:
+                k_c, v_c = k.astype(cache.k.dtype), v.astype(cache.v.dtype)
+            k_pool = cache.k.reshape(nb_pool * bs_blk, kvh, hd).at[
+                ragged.dest].set(k_c.reshape(-1, kvh, hd)).reshape(
+                    cache.k.shape)
+            v_pool = cache.v.reshape(nb_pool * bs_blk, kvh, hd).at[
+                ragged.dest].set(v_c.reshape(-1, kvh, hd)).reshape(
+                    cache.v.shape)
         from repro.kernels import ops as kops
-        out = kops.ragged_attention(
-            q[0], k_pool, v_pool, ragged.block_tables, ragged.q_start,
-            ragged.q_len, ragged.kv_len, kv_frac_bits=kv_frac_bits,
-            tq_max=ragged.tq_max, mesh=current_mesh(),
-            shard_axis=cfg.attn_shard_axis)[None]
-        out = constrain(out.reshape(b, s, h * hd), ("batch", None, "heads"))
-        return (linear(ctx, f"{name}/wo", out, p["wo"]),
-                PagedKVCache(k_pool, v_pool))
+        with jax.named_scope("attention"):
+            out = kops.ragged_attention(
+                q[0], k_pool, v_pool, ragged.block_tables, ragged.q_start,
+                ragged.q_len, ragged.kv_len, kv_frac_bits=kv_frac_bits,
+                tq_max=ragged.tq_max, mesh=current_mesh(),
+                shard_axis=cfg.attn_shard_axis)[None]
+        with jax.named_scope("attn_out"):
+            out = constrain(out.reshape(b, s, h * hd),
+                            ("batch", None, "heads"))
+            out = linear(ctx, f"{name}/wo", out, p["wo"])
+        return out, PagedKVCache(k_pool, v_pool)
 
     if isinstance(cache, PagedKVCache):
         # serving-engine paged path (DESIGN §9): quantize ONCE, scatter the

@@ -650,8 +650,12 @@ def ragged_step(params: dict, tokens: jax.Array, cache: Any,
         raise NotImplementedError(
             f"ragged_step covers GQA dense/vlm families; got {cfg.family!r}")
     dt = _dtype(cfg)
-    x = constrain(embed(params["embed"], tokens[None], dt),
-                  ("batch", None, None))
+    # named scopes (embed / layers / head, and the layer's own inside
+    # dense_block) tag each op's HLO metadata, so a device trace can
+    # charge every op of the step to a part of the model
+    with jax.named_scope("embed"):
+        x = constrain(embed(params["embed"], tokens[None], dt),
+                      ("batch", None, None))
 
     def body(x, inp):
         p_l, c_l = inp
@@ -660,10 +664,12 @@ def ragged_step(params: dict, tokens: jax.Array, cache: Any,
                                 ragged=ragged)
         return y, cl
 
-    x, kv = _scan(body, x, (params["blocks"], cache["paged_kv"]))
-    x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = unembed(ctx, x, head)
+    with jax.named_scope("layers"):
+        x, kv = _scan(body, x, (params["blocks"], cache["paged_kv"]))
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = unembed(ctx, x, head)
     return logits[0], {"paged_kv": kv}
 
 

@@ -78,13 +78,20 @@ def dense_block(ctx: QuantContext, p: dict, x: jax.Array, cfg: ModelConfig,
     # grids, so a block over int8 weight codes is bit-identical to the
     # float-weight INT forward module-for-module (the parity rig's
     # full-layer case leans on exactly this).
-    h, new_cache = _attn_dispatch(ctx, p, rmsnorm(x, p["ln1"], cfg.norm_eps),
-                                  cfg, positions, cache, cache_pos, use_rope,
-                                  block_tables, ragged)
-    x = constrain(x + h, ("batch", None, None))
-    x = x + mlp_lib.mlp(ctx, p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps),
-                        cfg.act)
-    return constrain(x, ("batch", None, None)), new_cache
+    # The scopes name the layer's parts in the ops' HLO metadata (the
+    # attention's own parts are named inside it); each norm and residual
+    # add goes with the part it feeds or closes.
+    with jax.named_scope("attn_qkv"):
+        xn = rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h, new_cache = _attn_dispatch(ctx, p, xn, cfg, positions, cache,
+                                  cache_pos, use_rope, block_tables, ragged)
+    with jax.named_scope("attn_out"):
+        x = constrain(x + h, ("batch", None, None))
+    with jax.named_scope("mlp"):
+        x = x + mlp_lib.mlp(ctx, p["mlp"], rmsnorm(x, p["ln2"], cfg.norm_eps),
+                            cfg.act)
+        x = constrain(x, ("batch", None, None))
+    return x, new_cache
 
 
 def init_moe_block(init: Initializer, cfg: ModelConfig) -> dict:

@@ -5,9 +5,11 @@ Three optional, independently-gated capabilities:
 * **`jax.profiler` capture windows** — :meth:`Profiler.capture` wraps a
   run of the engine in ``jax.profiler.trace(profile_dir)`` so the XLA
   trace (TensorBoard / Perfetto loadable) lines up with the host-side
-  obs trace; :meth:`Profiler.step_annotation` puts a
-  ``StepTraceAnnotation`` around each ``ragged_step`` dispatch so steps
-  are delimited inside the device trace.
+  obs trace.  While the profiler has a directory its tracer's
+  ``capturing`` flag is on, so every ``Tracer.phase`` of the engine
+  (``engine.step`` and its phases) is also a ``TraceAnnotation`` on the
+  profiler's clock.  A caller that runs its own ``jax.profiler`` window
+  sets ``profile_dir`` for its duration.
 * **Per-compiled-shape cost analysis** — :meth:`Profiler.cost_for`
   runs AOT ``lower(...).cost_analysis()`` once per compiled stream
   shape (FLOPs + bytes accessed per dispatch), memoized by the same
@@ -120,13 +122,26 @@ class Profiler:
     sites stay unconditional too."""
 
     def __init__(self, *, profile_dir: Optional[str] = None,
-                 cost: bool = False):
+                 cost: bool = False, tracer=None):
+        self.tracer = tracer
         self.profile_dir = profile_dir
         self.cost = cost
         self.shape_costs: dict[Any, dict] = {}
         # the lowered executable of each costed shape, so callers can
         # compile it and read what ran (``.compile().as_text()``)
         self.lowered: dict[Any, Any] = {}
+
+    @property
+    def profile_dir(self) -> Optional[str]:
+        return self._profile_dir
+
+    @profile_dir.setter
+    def profile_dir(self, path: Optional[str]) -> None:
+        """A directory means a capture is on: the tracer's phases then
+        annotate the profiler's trace."""
+        self._profile_dir = path
+        if self.tracer is not None:
+            self.tracer.capturing = path is not None
 
     @property
     def enabled(self) -> bool:
@@ -142,16 +157,6 @@ class Profiler:
             return
         import jax
         with jax.profiler.trace(self.profile_dir):
-            yield
-
-    @contextlib.contextmanager
-    def step_annotation(self, name: str, step: int):
-        """Delimit one jitted dispatch inside the device trace."""
-        if self.profile_dir is None:
-            yield
-            return
-        import jax
-        with jax.profiler.StepTraceAnnotation(name, step_num=step):
             yield
 
     # -- per-shape cost analysis ------------------------------------------

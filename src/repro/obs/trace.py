@@ -21,15 +21,24 @@ Two complementary stores, one object:
   the legacy request-timestamp lists (``tests/test_obs.py``,
   ``serving_bench --check``).
 
+* **Phases** — :meth:`Tracer.phase` is the one span API of the program
+  (engine phases, dispatches): one ``with`` block feeds two sinks, an
+  ``X`` span in the ring when ``enabled`` and a
+  ``jax.profiler.TraceAnnotation`` on the profiler's clock while
+  ``capturing``.  With both off it hands back one shared no-op context
+  and reads no clock.
+
 Export is Chrome trace-event JSON (the Perfetto-loadable subset:
 ``X``/``i``/``M`` phases, microsecond timestamps), see
 :meth:`Tracer.to_chrome` and ``examples/inspect_trace.py``.
 
 Pure Python (stdlib only) — safe to import from the jax-free host
-modules (kv_pool / scheduler / prefix_cache carry an optional tracer).
+modules (kv_pool / scheduler / prefix_cache carry an optional tracer);
+jax is imported only when a phase is entered during a capture.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import time
@@ -88,6 +97,34 @@ class Timeline:
         return (self.done - self.first_token) / (self.n_generated - 1)
 
 
+_NO_PHASE = contextlib.nullcontext()
+
+
+class _Phase:
+    """One live phase: a ring span and/or a profiler annotation."""
+    __slots__ = ("tracer", "name", "cat", "args", "annotation", "t0")
+
+    def __init__(self, tracer, name, cat, args, annotation):
+        self.tracer, self.name, self.cat = tracer, name, cat
+        self.args, self.annotation = args, annotation
+
+    def __enter__(self):
+        if self.annotation is not None:
+            self.annotation.__enter__()
+        tr = self.tracer
+        self.t0 = tr.clock() if tr.enabled else None
+        return self
+
+    def __exit__(self, *exc):
+        tr = self.tracer
+        if self.t0 is not None:
+            tr.span(self.name, self.cat, self.t0, tr.clock() - self.t0,
+                    self.args)
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+        return False
+
+
 class Tracer:
     """Ring-buffered structured events + always-on request timelines."""
 
@@ -110,6 +147,9 @@ class Tracer:
         # decision sink never does (record mode only, bounded by the
         # workload's own decision count).
         self.decision_sink: Optional[list] = None
+        # a jax.profiler capture is on: phases also enter a
+        # TraceAnnotation.  Set by ``Profiler`` while it has a directory.
+        self.capturing = False
 
     # -- ring events ------------------------------------------------------
 
@@ -140,6 +180,23 @@ class Tracer:
             return
         self.n_emitted += 1
         self.events.append(("X", name, cat, ts, dur, args))
+
+    def phase(self, name: str, cat: str, args: Optional[dict] = None,
+              step: Optional[int] = None):
+        """Context manager around one phase of work.  Records an ``X``
+        span in the ring when ``enabled``; while ``capturing`` enters
+        ``jax.profiler.TraceAnnotation(name)`` — a
+        ``StepTraceAnnotation`` when ``step`` is given, so profiler tools
+        see step boundaries.  With both off it returns a shared no-op
+        context and reads no clock."""
+        if not self.capturing:
+            if not self.enabled:
+                return _NO_PHASE
+            return _Phase(self, name, cat, args, None)
+        import jax
+        ann = jax.profiler.TraceAnnotation(name) if step is None \
+            else jax.profiler.StepTraceAnnotation(name, step_num=step)
+        return _Phase(self, name, cat, args, ann)
 
     # -- request timelines (always on) ------------------------------------
 

@@ -37,7 +37,7 @@ a dequantize-the-cache-every-step dataflow would have executed.
 from __future__ import annotations
 
 import time
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -253,7 +253,8 @@ class ServingEngine:
             objectives = default_slos() if slo is True else slo
             self.slo = SLOMonitor(objectives, tracer=self.tracer,
                                   value_fn=self._metric_value)
-        self.profiler = Profiler(profile_dir=profile_dir, cost=profile_cost)
+        self.profiler = Profiler(profile_dir=profile_dir, cost=profile_cost,
+                                 tracer=self.tracer)
         # live Table-5 energy proxy, split prefill / decode / spec_wasted;
         # reconciles exactly with the requant counters below (tested)
         self.energy = EnergyAccount("bit_shifting")
@@ -336,15 +337,17 @@ class ServingEngine:
                                     step_idx, k_cap):
                 logits, cache = base_ragged(params, tokens, cache,
                                             positions, rb)
-                t = logits.shape[0]
-                idx = jnp.clip(sample_start[:, None]
-                               + jnp.arange(kp1, dtype=jnp.int32)[None, :],
-                               0, t - 1)
-                rows = jnp.take(logits, idx, axis=0)    # (S, K+1, V)
-                toks = jnp.take(tokens, idx, axis=0)    # (S, K+1)
-                key = jax.random.fold_in(base_key, step_idx)
-                out, n_acc = verify_tokens(rows, toks, n_drafts, key,
-                                           temps, topks, k_cap=k_cap)
+                with jax.named_scope("sample"):
+                    t = logits.shape[0]
+                    idx = jnp.clip(
+                        sample_start[:, None]
+                        + jnp.arange(kp1, dtype=jnp.int32)[None, :],
+                        0, t - 1)
+                    rows = jnp.take(logits, idx, axis=0)    # (S, K+1, V)
+                    toks = jnp.take(tokens, idx, axis=0)    # (S, K+1)
+                    key = jax.random.fold_in(base_key, step_idx)
+                    out, n_acc = verify_tokens(rows, toks, n_drafts, key,
+                                               temps, topks, k_cap=k_cap)
                 return out, n_acc, cache
 
             self._ragged_fn = jax.jit(ragged_sampled_step,
@@ -458,6 +461,10 @@ class ServingEngine:
         self._step_times: dict[tuple, list] = {}    # shape key -> wall s
         self._t0 = time.perf_counter()
         self._skip = 0.0
+        # the engine clock (seconds): whoever drives the engine may hand
+        # it their own, so arrivals, timeline marks and ring spans all
+        # lie on the caller's clock; ``run`` uses this default
+        self.clock: Callable[[], float] = self._run_clock
         self._wall_s = 0.0
         # the registry is the single source of report naming/typing:
         # report() is a nested view of it (DESIGN §14)
@@ -467,12 +474,17 @@ class ServingEngine:
     # -- clock ------------------------------------------------------------
 
     def _now(self) -> float:
-        """Engine clock, seconds.  Real (monotonic minus fast-forwarded
-        idle gaps) normally; the deterministic VIRTUAL clock under
-        ``record=True`` — every timeline mark, trace timestamp and SLO
-        window then replays bit-identically (DESIGN §15)."""
+        """Engine clock, seconds: ``self.clock`` normally; the
+        deterministic VIRTUAL clock under ``record=True`` — every
+        timeline mark, trace timestamp and SLO window then replays
+        bit-identically (DESIGN §15)."""
         if self._virtual_time is not None:
             return self._virtual_time
+        return self.clock()
+
+    def _run_clock(self) -> float:
+        """The default clock: monotonic minus the idle gaps ``run``
+        fast-forwarded."""
         return time.perf_counter() - self._t0 + self._skip
 
     def _metric_value(self, name: str):
@@ -597,7 +609,26 @@ class ServingEngine:
         dispatch over the mixed work-list.  Legacy (``ragged=False``):
         admit → chunked prefill → decode (a speculative verify step when
         drafting is on and produced drafts, the plain (B, 1) decode
-        otherwise)."""
+        otherwise).  The whole call is one ``engine.step`` phase; its
+        children name where the host time goes (``engine.admit``,
+        ``engine.plan``, ``engine.pack``, ``engine.transfer``,
+        ``engine.launch``, ``engine.sync``, ``engine.commit``)."""
+        tr = self.tracer
+        with tr.phase("engine.step", "engine", step=self._step_counter):
+            with tr.phase("engine.admit", "engine"):
+                self._admit()
+            if self.substrate.fixed_state:
+                self._run_recurrent_step()
+            elif self.ragged:
+                self._run_ragged_step()
+            else:
+                self._run_prefills()
+                if not (self.spec_k and self._run_spec_decode()):
+                    self._run_decode()
+            if self.slo is not None:
+                self.slo.evaluate(self._now())
+
+    def _admit(self) -> None:
         for req in self.sched.admit(self._now()):
             if self.substrate.fixed_state:
                 if req.snapshot is not None:
@@ -619,16 +650,6 @@ class ServingEngine:
             # tokens — none of their matmul-boundary quant ops ever run
             self.requant_ops_forward_avoided_cache += \
                 req.n_prefilled * self._fwd_elems_per_token
-        if self.substrate.fixed_state:
-            self._run_recurrent_step()
-        elif self.ragged:
-            self._run_ragged_step()
-        else:
-            self._run_prefills()
-            if not (self.spec_k and self._run_spec_decode()):
-                self._run_decode()
-        if self.slo is not None:
-            self.slo.evaluate(self._now())
 
     # -- unified ragged step (DESIGN §12) ---------------------------------
 
@@ -654,213 +675,219 @@ class ServingEngine:
         arrays are built — a preempted request's chunk simply drops out
         of this step, the same outcome the phase-ordered path reaches by
         dispatching before planning the next phase."""
-        now = self._now()
-        budget = self.sched.prefill_token_budget
-        prefill_items = []                  # (req, start, c_real)
-        for req in self.sched.prefill_jobs():
-            if budget <= 0:
-                break
-            start = req.n_prefilled
-            c_real = min(self.sched.chunk, len(req.feed) - start, budget)
-            # copy-on-write (DESIGN §10): any block this chunk writes
-            # into must be private (returns False iff req was preempted)
-            if not self._cow_for_range(req, start, start + c_real):
-                continue
-            budget -= c_real
-            prefill_items.append((req, start, c_real))
+        tr = self.tracer
+        with tr.phase("engine.plan", "engine"):
+            now = self._now()
+            budget = self.sched.prefill_token_budget
+            prefill_items = []                  # (req, start, c_real)
+            for req in self.sched.prefill_jobs():
+                if budget <= 0:
+                    break
+                start = req.n_prefilled
+                c_real = min(self.sched.chunk, len(req.feed) - start, budget)
+                # copy-on-write (DESIGN §10): any block this chunk writes
+                # into must be private (returns False iff req was preempted)
+                if not self._cow_for_range(req, start, start + c_real):
+                    continue
+                budget -= c_real
+                prefill_items.append((req, start, c_real))
 
-        proposals = {}
-        if self.spec_k:
-            for req in self.sched.decode_reqs():
-                b = self._spec_budget(req)
-                if b > 0:
-                    d = np.asarray(self.drafter.draft(
-                        np.concatenate([req.prompt, np.asarray(
-                            req.generated, np.int32)]), b), np.int32)
-                    proposals[req.rid] = d[:b]
-        has_spec = any(len(d) for d in proposals.values())
-        plans: dict[int, np.ndarray] = {}
-        for req in list(self.sched.decode_reqs()):
-            if req.slot is None or req.state is not RequestState.DECODE:
-                continue
-            drafts = proposals.get(req.rid, np.empty(0, np.int32))
-            if has_spec:
-                granted = self.sched.grow_for_spec(req, now, len(drafts))
-                if granted is None:
-                    continue                # req itself was preempted
-                drafts = drafts[:granted]
-                # the speculative tail must only write private blocks
-                if not self._cow_for_range(req, req.n_ctx,
-                                           req.n_ctx + 1 + len(drafts)):
-                    continue                # req itself was preempted
-            elif not self.sched.grow_for_decode(req, now):
-                continue                    # req itself was preempted
-            plans[req.rid] = drafts
+            proposals = {}
+            if self.spec_k:
+                for req in self.sched.decode_reqs():
+                    b = self._spec_budget(req)
+                    if b > 0:
+                        d = np.asarray(self.drafter.draft(
+                            np.concatenate([req.prompt, np.asarray(
+                                req.generated, np.int32)]), b), np.int32)
+                        proposals[req.rid] = d[:b]
+            has_spec = any(len(d) for d in proposals.values())
+            plans: dict[int, np.ndarray] = {}
+            for req in list(self.sched.decode_reqs()):
+                if req.slot is None or req.state is not RequestState.DECODE:
+                    continue
+                drafts = proposals.get(req.rid, np.empty(0, np.int32))
+                if has_spec:
+                    granted = self.sched.grow_for_spec(req, now, len(drafts))
+                    if granted is None:
+                        continue                # req itself was preempted
+                    drafts = drafts[:granted]
+                    # the speculative tail must only write private blocks
+                    if not self._cow_for_range(req, req.n_ctx,
+                                               req.n_ctx + 1 + len(drafts)):
+                        continue                # req itself was preempted
+                elif not self.sched.grow_for_decode(req, now):
+                    continue                    # req itself was preempted
+                plans[req.rid] = drafts
 
-        # re-validate: growth/CoW above may have preempted planned items
-        prefill_items = [
-            (r, s, c) for (r, s, c) in prefill_items
-            if r.slot is not None and r.state is RequestState.PREFILL
-            and r.n_prefilled == s]
-        decode_items = [(r, plans[r.rid]) for r in self.sched.decode_reqs()
-                        if r.rid in plans]
-        if not prefill_items and not decode_items:
-            return
+            # re-validate: growth/CoW above may have preempted planned items
+            prefill_items = [
+                (r, s, c) for (r, s, c) in prefill_items
+                if r.slot is not None and r.state is RequestState.PREFILL
+                and r.n_prefilled == s]
+            decode_items = [(r, plans[r.rid]) for r in self.sched.decode_reqs()
+                            if r.rid in plans]
+            if not prefill_items and not decode_items:
+                return
 
-        # -- build the flattened stream + descriptors ---------------------
-        bs = self.pool.block_size
-        nbmax = self.sched.nbmax
-        s_pad = self.n_slots
-        q_lens = [c for (_, _, c) in prefill_items] \
-            + [1 + len(d) for (_, d) in decode_items]
-        t_real = sum(q_lens)
-        t_pad = self._t_bucket(t_real)
-        tokens = np.zeros(t_pad, np.int32)
-        positions = np.zeros(t_pad, np.int32)
-        dest = np.zeros(t_pad, np.int32)    # padding rows scatter to trash
-        q_start = np.full(s_pad, t_pad, np.int32)
-        q_len = np.zeros(s_pad, np.int32)
-        kv_len = np.zeros(s_pad, np.int32)
-        bt = np.full((s_pad, nbmax), TRASH_BLOCK, np.int32)
-        temps = np.zeros(s_pad, np.float32)
-        topks = np.zeros(s_pad, np.int32)
-        sample_start = np.zeros(s_pad, np.int32)
-        n_drafts = np.zeros(s_pad, np.int32)
-        fed: list[np.ndarray] = []
-        off = 0
-        for i, (req, item) in enumerate(
-                [(r, (s, c)) for (r, s, c) in prefill_items]
-                + [(r, d) for (r, d) in decode_items]):
-            if i < len(prefill_items):
-                start, c_real = item
-                toks_i = np.asarray(req.feed[start:start + c_real], np.int32)
-                pos_i = start + np.arange(c_real, dtype=np.int32)
-                sample_start[i] = off + c_real - 1     # last real row
-            else:
-                d = item
-                toks_i = np.concatenate(
-                    [[req.generated[-1]], d]).astype(np.int32)
-                pos_i = req.n_ctx + np.arange(1 + len(d), dtype=np.int32)
-                sample_start[i] = off                  # fed-token row
-                n_drafts[i] = len(d)
-            n = len(toks_i)
-            row = self.pool.table_row(req.rid, nbmax)
-            tokens[off:off + n] = toks_i
-            positions[off:off + n] = pos_i
-            dest[off:off + n] = row[pos_i // bs] * bs + pos_i % bs
-            q_start[i] = off
-            q_len[i] = n
-            kv_len[i] = int(pos_i[-1]) + 1
-            bt[i] = row
-            temps[i] = req.temperature
-            topks[i] = self._req_top_k(req)
-            fed.append(toks_i)
-            off += n
+        with tr.phase("engine.pack", "engine"):
+            # -- build the flattened stream + descriptors -----------------
+            bs = self.pool.block_size
+            nbmax = self.sched.nbmax
+            s_pad = self.n_slots
+            q_lens = [c for (_, _, c) in prefill_items] \
+                + [1 + len(d) for (_, d) in decode_items]
+            t_real = sum(q_lens)
+            t_pad = self._t_bucket(t_real)
+            tokens = np.zeros(t_pad, np.int32)
+            positions = np.zeros(t_pad, np.int32)
+            dest = np.zeros(t_pad, np.int32)  # padding rows scatter to trash
+            q_start = np.full(s_pad, t_pad, np.int32)
+            q_len = np.zeros(s_pad, np.int32)
+            kv_len = np.zeros(s_pad, np.int32)
+            bt = np.full((s_pad, nbmax), TRASH_BLOCK, np.int32)
+            temps = np.zeros(s_pad, np.float32)
+            topks = np.zeros(s_pad, np.int32)
+            sample_start = np.zeros(s_pad, np.int32)
+            n_drafts = np.zeros(s_pad, np.int32)
+            fed: list[np.ndarray] = []
+            off = 0
+            for i, (req, item) in enumerate(
+                    [(r, (s, c)) for (r, s, c) in prefill_items]
+                    + [(r, d) for (r, d) in decode_items]):
+                if i < len(prefill_items):
+                    start, c_real = item
+                    toks_i = np.asarray(req.feed[start:start + c_real],
+                                        np.int32)
+                    pos_i = start + np.arange(c_real, dtype=np.int32)
+                    sample_start[i] = off + c_real - 1     # last real row
+                else:
+                    d = item
+                    toks_i = np.concatenate(
+                        [[req.generated[-1]], d]).astype(np.int32)
+                    pos_i = req.n_ctx + np.arange(1 + len(d),
+                                                  dtype=np.int32)
+                    sample_start[i] = off                  # fed-token row
+                    n_drafts[i] = len(d)
+                n = len(toks_i)
+                row = self.pool.table_row(req.rid, nbmax)
+                tokens[off:off + n] = toks_i
+                positions[off:off + n] = pos_i
+                dest[off:off + n] = row[pos_i // bs] * bs + pos_i % bs
+                q_start[i] = off
+                q_len[i] = n
+                kv_len[i] = int(pos_i[-1]) + 1
+                bt[i] = row
+                temps[i] = req.temperature
+                topks[i] = self._req_top_k(req)
+                fed.append(toks_i)
+                off += n
         out, n_acc = self._dispatch_ragged(tokens, positions, dest, bt,
                                            q_start, q_len, kv_len, temps,
                                            topks, sample_start, n_drafts,
                                            t_real=t_real)
-        self.ragged_steps += 1
-        self.dispatched_tokens += t_pad
-        self.padded_tokens += t_pad - t_real
-        now = self._now()
-        tr = self.tracer
-        ept = self._elems_per_token + self._fwd_elems_per_token
+        with tr.phase("engine.commit", "engine"):
+            self.ragged_steps += 1
+            self.dispatched_tokens += t_pad
+            self.padded_tokens += t_pad - t_real
+            now = self._now()
+            ept = self._elems_per_token + self._fwd_elems_per_token
 
-        # -- post-process: prefill items (mirrors _prefill_chunk) ---------
-        for i, (req, start, c_real) in enumerate(prefill_items):
-            req.n_prefilled += c_real
-            req.n_ctx = req.n_prefilled
-            self.pool.commit(req.rid, start,
-                             req.feed[start:start + c_real])
-            self.prefill_chunks += 1
-            self.requant_ops_performed += c_real * self._elems_per_token
-            self.requant_ops_forward += c_real * self._fwd_elems_per_token
-            self.energy.charge("prefill", c_real * ept, c_real)
-            if tr.enabled:
-                # chunk boundary: part of the scheduler-decision stream
-                # the flight recorder diffs between runs (DESIGN §15)
-                tr.event("sched.prefill_chunk", "sched", ts=now, args={
-                    "rid": req.rid, "start": start, "tokens": c_real})
-            tr.req_mark(req.rid, "first_chunk", now)
-            if req.n_prefilled == len(req.feed):
-                tok = int(out[i, 0])
-                if req.t_first is None:
-                    req.t_first = now
-                tr.req_mark(req.rid, "first_token", now)
-                tr.req_token(req.rid, now)
-                done = req.finished_by(tok, self.max_model_len)
-                req.generated.append(tok)
-                if done:
-                    self.sched.finish(req, now)
-                else:
-                    req.state = RequestState.DECODE
-
-        # -- post-process: decode items (mirrors _run_decode / spec) ------
-        if decode_items:
-            if has_spec:
-                self.spec_steps += 1
-                self.spec_slot_steps += len(decode_items)
-            else:
-                self.decode_steps += 1
-        for j, (req, d) in enumerate(decode_items):
-            i = len(prefill_items) + j
-            fed_tok = int(fed[i][0])
-            if has_spec:
-                acc = int(n_acc[i])
-                emitted = out[i, :acc + 1].tolist()
-                kept_drafts = 0
-                n_out = 0
-                done = False
-                for k, tok in enumerate(emitted):
-                    done = req.finished_by(int(tok), self.max_model_len)
-                    req.generated.append(int(tok))
+            # -- post-process: prefill items (mirrors _prefill_chunk) ---------
+            for i, (req, start, c_real) in enumerate(prefill_items):
+                req.n_prefilled += c_real
+                req.n_ctx = req.n_prefilled
+                self.pool.commit(req.rid, start,
+                                 req.feed[start:start + c_real])
+                self.prefill_chunks += 1
+                self.requant_ops_performed += c_real * self._elems_per_token
+                self.requant_ops_forward += c_real * self._fwd_elems_per_token
+                self.energy.charge("prefill", c_real * ept, c_real)
+                if tr.enabled:
+                    # chunk boundary: part of the scheduler-decision stream
+                    # the flight recorder diffs between runs (DESIGN §15)
+                    tr.event("sched.prefill_chunk", "sched", ts=now, args={
+                        "rid": req.rid, "start": start, "tokens": c_real})
+                tr.req_mark(req.rid, "first_chunk", now)
+                if req.n_prefilled == len(req.feed):
+                    tok = int(out[i, 0])
+                    if req.t_first is None:
+                        req.t_first = now
+                    tr.req_mark(req.rid, "first_token", now)
                     tr.req_token(req.rid, now)
-                    self.spec_emitted += 1
-                    n_out += 1
-                    if k < acc:
-                        kept_drafts += 1   # this draft's KV row is resident
+                    done = req.finished_by(tok, self.max_model_len)
+                    req.generated.append(tok)
                     if done:
-                        break
-                self.pool.commit(req.rid, req.n_ctx,
-                                 [fed_tok] + d[:kept_drafts].tolist())
-                self.requant_ops_performed += \
-                    (1 + len(d)) * self._elems_per_token
-                self.requant_ops_wasted_spec += \
-                    (len(d) - kept_drafts) * self._elems_per_token
-                # every fed row (real token + all drafts) ran the W8A8
-                # forward; rejected drafts' forward ops are pure waste
-                self.requant_ops_forward += \
-                    (1 + len(d)) * self._fwd_elems_per_token
-                self.requant_ops_forward_wasted_spec += \
-                    (len(d) - kept_drafts) * self._fwd_elems_per_token
-                self.energy.charge("decode", (1 + kept_drafts) * ept, n_out)
-                self.energy.charge("spec_wasted",
-                                   (len(d) - kept_drafts) * ept,
-                                   len(d) - kept_drafts)
-                self.spec_drafted += len(d)
-                self.spec_accepted += acc
-                req.n_ctx += 1 + kept_drafts
-                if done:
-                    self.sched.finish(req, now)
+                        self.sched.finish(req, now)
+                    else:
+                        req.state = RequestState.DECODE
+
+            # -- post-process: decode items (mirrors _run_decode / spec) ------
+            if decode_items:
+                if has_spec:
+                    self.spec_steps += 1
+                    self.spec_slot_steps += len(decode_items)
                 else:
-                    self.pool.retract(req.rid, req.n_ctx)
-                self.requant_ops_avoided += \
-                    req.n_ctx * self._elems_per_token
-            else:
-                self.pool.commit(req.rid, req.n_ctx, [fed_tok])
-                self.requant_ops_performed += self._elems_per_token
-                self.requant_ops_forward += self._fwd_elems_per_token
-                self.energy.charge("decode", ept, 1)
-                req.n_ctx += 1
-                self.requant_ops_avoided += \
-                    req.n_ctx * self._elems_per_token
-                tok = int(out[i, 0])
-                done = req.finished_by(tok, self.max_model_len)
-                req.generated.append(tok)
-                tr.req_token(req.rid, now)
-                if done:
-                    self.sched.finish(req, now)
+                    self.decode_steps += 1
+            for j, (req, d) in enumerate(decode_items):
+                i = len(prefill_items) + j
+                fed_tok = int(fed[i][0])
+                if has_spec:
+                    acc = int(n_acc[i])
+                    emitted = out[i, :acc + 1].tolist()
+                    kept_drafts = 0
+                    n_out = 0
+                    done = False
+                    for k, tok in enumerate(emitted):
+                        done = req.finished_by(int(tok), self.max_model_len)
+                        req.generated.append(int(tok))
+                        tr.req_token(req.rid, now)
+                        self.spec_emitted += 1
+                        n_out += 1
+                        if k < acc:
+                            kept_drafts += 1   # its KV row is resident
+                        if done:
+                            break
+                    self.pool.commit(req.rid, req.n_ctx,
+                                     [fed_tok] + d[:kept_drafts].tolist())
+                    self.requant_ops_performed += \
+                        (1 + len(d)) * self._elems_per_token
+                    self.requant_ops_wasted_spec += \
+                        (len(d) - kept_drafts) * self._elems_per_token
+                    # every fed row (real token + all drafts) ran the W8A8
+                    # forward; rejected drafts' forward ops are pure waste
+                    self.requant_ops_forward += \
+                        (1 + len(d)) * self._fwd_elems_per_token
+                    self.requant_ops_forward_wasted_spec += \
+                        (len(d) - kept_drafts) * self._fwd_elems_per_token
+                    self.energy.charge("decode", (1 + kept_drafts) * ept,
+                                       n_out)
+                    self.energy.charge("spec_wasted",
+                                       (len(d) - kept_drafts) * ept,
+                                       len(d) - kept_drafts)
+                    self.spec_drafted += len(d)
+                    self.spec_accepted += acc
+                    req.n_ctx += 1 + kept_drafts
+                    if done:
+                        self.sched.finish(req, now)
+                    else:
+                        self.pool.retract(req.rid, req.n_ctx)
+                    self.requant_ops_avoided += \
+                        req.n_ctx * self._elems_per_token
+                else:
+                    self.pool.commit(req.rid, req.n_ctx, [fed_tok])
+                    self.requant_ops_performed += self._elems_per_token
+                    self.requant_ops_forward += self._fwd_elems_per_token
+                    self.energy.charge("decode", ept, 1)
+                    req.n_ctx += 1
+                    self.requant_ops_avoided += \
+                        req.n_ctx * self._elems_per_token
+                    tok = int(out[i, 0])
+                    done = req.finished_by(tok, self.max_model_len)
+                    req.generated.append(tok)
+                    tr.req_token(req.rid, now)
+                    if done:
+                        self.sched.finish(req, now)
 
     def _dispatch_ragged(self, tokens, positions, dest, bt, q_start, q_len,
                          kv_len, temps, topks, sample_start, n_drafts,
@@ -878,29 +905,28 @@ class ServingEngine:
         self._step_counter += 1
         topks = np.asarray(topks)
         cap = int(topks.max()) if topks.any() else None
-        topks_arg = jnp.asarray(topks) if topks.any() else None
-        rb = RaggedBatch(
-            dest=jnp.asarray(dest), block_tables=jnp.asarray(bt),
-            q_start=jnp.asarray(q_start), q_len=jnp.asarray(q_len),
-            kv_len=jnp.asarray(kv_len), tq_max=self._tq_max)
         shape_key = ("ragged", len(tokens), len(temps))
         first_call = shape_key not in self._step_times
-        args = (self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(positions), rb, jnp.asarray(temps), topks_arg,
-                jnp.asarray(sample_start), jnp.asarray(n_drafts),
-                jnp.asarray(self._step_counter, jnp.uint32), cap)
+        tr = self.tracer
+        with tr.phase("engine.transfer", "engine"):
+            topks_arg = jnp.asarray(topks) if topks.any() else None
+            rb = RaggedBatch(
+                dest=jnp.asarray(dest), block_tables=jnp.asarray(bt),
+                q_start=jnp.asarray(q_start), q_len=jnp.asarray(q_len),
+                kv_len=jnp.asarray(kv_len), tq_max=self._tq_max)
+            args = (self.params, jnp.asarray(tokens), self.cache,
+                    jnp.asarray(positions), rb, jnp.asarray(temps),
+                    topks_arg, jnp.asarray(sample_start),
+                    jnp.asarray(n_drafts),
+                    jnp.asarray(self._step_counter, jnp.uint32), cap)
         if self.profiler.cost:
             self.profiler.cost_for(shape_key, self._ragged_fn, *args)
-        if self.profiler.profile_dir is not None:
-            with self.profiler.step_annotation("ragged_step",
-                                               self._step_counter):
-                out, n_acc, self.cache = self._ragged_fn(*args)
-        else:
+        with tr.phase("engine.launch", "engine"):
             out, n_acc, self.cache = self._ragged_fn(*args)
-        out, n_acc = np.asarray(out), np.asarray(n_acc)   # host sync
+        with tr.phase("engine.sync", "engine"):
+            out, n_acc = np.asarray(out), np.asarray(n_acc)
         dt = time.perf_counter() - t0
         self._step_times.setdefault(shape_key, []).append(dt)
-        tr = self.tracer
         if tr.enabled:
             tr.span("ragged_step", "dispatch", t_start, dt, {
                 "shape": f"T{len(tokens)}xS{len(temps)}",
@@ -1262,118 +1288,121 @@ class ServingEngine:
         slabs while the shared attention blocks scatter/gather the paged
         KV pool, in the same jitted step."""
         sub = self.substrate
-        now = self._now()
-        if sub.grows:
-            # hybrid KV half: decode rows append one KV row per step, so
-            # block tables may need to grow — growth can preempt a peer,
-            # exactly like the attention decode path
-            for req in list(self.sched.decode_reqs()):
-                if req.slot is not None \
-                        and req.state is RequestState.DECODE:
-                    self.sched.grow_for_decode(req, now)
-        prefills = []
-        for req in self.sched.prefill_jobs():
-            start = req.n_prefilled
-            c_real = min(self.sched.chunk, len(req.feed) - start)
-            prefills.append((req, start, c_real))
-        decodes = self.sched.decode_reqs()
-        if not prefills and not decodes:
-            return
-        b, c = self.n_slots, self.sched.chunk
-        tokens = np.zeros((b, c), np.int32)
-        q_len = np.zeros((b,), np.int32)
-        slab_ids = np.full((b,), TRASH_SLAB, np.int32)   # idle lanes
-        temps = np.zeros((b,), np.float32)
-        topks = np.zeros((b,), np.int32)
-        if sub.grows:
-            # one guaranteed-TRASH table column past nbmax: idle/padded
-            # positions point there, so their KV scatter lands in the
-            # trash block even for a full-length sequence
-            width = self.sched.nbmax + 1
-            pad_pos = self.sched.nbmax * self.pool.block_size
-            positions = np.full((b, c), pad_pos, np.int32)
-            bt = np.full((b, width), TRASH_BLOCK, np.int32)
-        else:
-            positions = bt = None
-        for req, start, c_real in prefills:
-            s = req.slot
-            tokens[s, :c_real] = req.feed[start:start + c_real]
-            q_len[s] = c_real
-            slab_ids[s] = self.state_pool.slab_of(req.rid)
-            temps[s] = req.temperature
-            topks[s] = self._req_top_k(req)
+        tr = self.tracer
+        with tr.phase("engine.plan", "engine"):
+            now = self._now()
             if sub.grows:
-                positions[s, :c_real] = start + np.arange(c_real,
-                                                          dtype=np.int32)
-                bt[s, :self.sched.nbmax] = self.pool.table_row(
-                    req.rid, self.sched.nbmax)
-        for req in decodes:
-            s = req.slot
-            tokens[s, 0] = req.generated[-1]
-            q_len[s] = 1
-            slab_ids[s] = self.state_pool.slab_of(req.rid)
-            temps[s] = req.temperature
-            topks[s] = self._req_top_k(req)
+                # hybrid KV half: decode rows append one KV row per step, so
+                # block tables may need to grow — growth can preempt a peer,
+                # exactly like the attention decode path
+                for req in list(self.sched.decode_reqs()):
+                    if req.slot is not None \
+                            and req.state is RequestState.DECODE:
+                        self.sched.grow_for_decode(req, now)
+            prefills = []
+            for req in self.sched.prefill_jobs():
+                start = req.n_prefilled
+                c_real = min(self.sched.chunk, len(req.feed) - start)
+                prefills.append((req, start, c_real))
+            decodes = self.sched.decode_reqs()
+            if not prefills and not decodes:
+                return
+        with tr.phase("engine.pack", "engine"):
+            b, c = self.n_slots, self.sched.chunk
+            tokens = np.zeros((b, c), np.int32)
+            q_len = np.zeros((b,), np.int32)
+            slab_ids = np.full((b,), TRASH_SLAB, np.int32)   # idle lanes
+            temps = np.zeros((b,), np.float32)
+            topks = np.zeros((b,), np.int32)
             if sub.grows:
-                positions[s, 0] = req.n_ctx
-                bt[s, :self.sched.nbmax] = self.pool.table_row(
-                    req.rid, self.sched.nbmax)
+                # one guaranteed-TRASH table column past nbmax: idle/padded
+                # positions point there, so their KV scatter lands in the
+                # trash block even for a full-length sequence
+                width = self.sched.nbmax + 1
+                pad_pos = self.sched.nbmax * self.pool.block_size
+                positions = np.full((b, c), pad_pos, np.int32)
+                bt = np.full((b, width), TRASH_BLOCK, np.int32)
+            else:
+                positions = bt = None
+            for req, start, c_real in prefills:
+                s = req.slot
+                tokens[s, :c_real] = req.feed[start:start + c_real]
+                q_len[s] = c_real
+                slab_ids[s] = self.state_pool.slab_of(req.rid)
+                temps[s] = req.temperature
+                topks[s] = self._req_top_k(req)
+                if sub.grows:
+                    positions[s, :c_real] = start + np.arange(c_real,
+                                                              dtype=np.int32)
+                    bt[s, :self.sched.nbmax] = self.pool.table_row(
+                        req.rid, self.sched.nbmax)
+            for req in decodes:
+                s = req.slot
+                tokens[s, 0] = req.generated[-1]
+                q_len[s] = 1
+                slab_ids[s] = self.state_pool.slab_of(req.rid)
+                temps[s] = req.temperature
+                topks[s] = self._req_top_k(req)
+                if sub.grows:
+                    positions[s, 0] = req.n_ctx
+                    bt[s, :self.sched.nbmax] = self.pool.table_row(
+                        req.rid, self.sched.nbmax)
         n_real = int(q_len.sum())
         toks = self._dispatch_recurrent(tokens, slab_ids, q_len,
                                         positions, bt, temps, topks,
                                         n_real)
-        self.recurrent_steps += 1
-        self.dispatched_tokens += b * c
-        self.padded_tokens += b * c - n_real
-        int8_state = self.cfg.state_bits == 8
-        now = self._now()
-        tr = self.tracer
+        with tr.phase("engine.commit", "engine"):
+            self.recurrent_steps += 1
+            self.dispatched_tokens += b * c
+            self.padded_tokens += b * c - n_real
+            int8_state = self.cfg.state_bits == 8
+            now = self._now()
 
-        # -- post-process: prefill rows (mirrors _prefill_chunk) ----------
-        for req, start, c_real in prefills:
-            req.n_prefilled += c_real
-            req.n_ctx = req.n_prefilled
-            if sub.grows:
-                self.pool.commit(req.rid, start,
-                                 req.feed[start:start + c_real])
-            self.prefill_chunks += 1
-            self._charge_recurrent("prefill", c_real, int8_state)
-            if tr.enabled:
-                # chunk boundary: part of the scheduler-decision stream
-                # the flight recorder diffs between runs (DESIGN §15)
-                tr.event("sched.prefill_chunk", "sched", ts=now, args={
-                    "rid": req.rid, "start": start, "tokens": c_real})
-            tr.req_mark(req.rid, "first_chunk", now)
-            if req.n_prefilled == len(req.feed):
+            # -- post-process: prefill rows (mirrors _prefill_chunk) ----------
+            for req, start, c_real in prefills:
+                req.n_prefilled += c_real
+                req.n_ctx = req.n_prefilled
+                if sub.grows:
+                    self.pool.commit(req.rid, start,
+                                     req.feed[start:start + c_real])
+                self.prefill_chunks += 1
+                self._charge_recurrent("prefill", c_real, int8_state)
+                if tr.enabled:
+                    # chunk boundary: part of the scheduler-decision stream
+                    # the flight recorder diffs between runs (DESIGN §15)
+                    tr.event("sched.prefill_chunk", "sched", ts=now, args={
+                        "rid": req.rid, "start": start, "tokens": c_real})
+                tr.req_mark(req.rid, "first_chunk", now)
+                if req.n_prefilled == len(req.feed):
+                    tok = int(toks[req.slot])
+                    if req.t_first is None:
+                        req.t_first = now
+                    tr.req_mark(req.rid, "first_token", now)
+                    tr.req_token(req.rid, now)
+                    done = req.finished_by(tok, self.max_model_len)
+                    req.generated.append(tok)
+                    if done:
+                        self.sched.finish(req, now)
+                    else:
+                        req.state = RequestState.DECODE
+
+            # -- post-process: decode rows (mirrors _run_decode) --------------
+            for req in decodes:
+                if sub.grows:
+                    self.pool.commit(req.rid, req.n_ctx, [req.generated[-1]])
+                req.n_ctx += 1
+                if sub.grows:
+                    # the hybrid's KV half still avoids the dequantize-the-
+                    # whole-cache-per-step counterfactual, same as attention
+                    self.requant_ops_avoided += \
+                        req.n_ctx * self._elems_per_token
+                self._charge_recurrent("decode", 1, int8_state)
                 tok = int(toks[req.slot])
-                if req.t_first is None:
-                    req.t_first = now
-                tr.req_mark(req.rid, "first_token", now)
-                tr.req_token(req.rid, now)
                 done = req.finished_by(tok, self.max_model_len)
                 req.generated.append(tok)
+                tr.req_token(req.rid, now)
                 if done:
                     self.sched.finish(req, now)
-                else:
-                    req.state = RequestState.DECODE
-
-        # -- post-process: decode rows (mirrors _run_decode) --------------
-        for req in decodes:
-            if sub.grows:
-                self.pool.commit(req.rid, req.n_ctx, [req.generated[-1]])
-            req.n_ctx += 1
-            if sub.grows:
-                # the hybrid's KV half still avoids the dequantize-the-
-                # whole-cache-per-step counterfactual, same as attention
-                self.requant_ops_avoided += \
-                    req.n_ctx * self._elems_per_token
-            self._charge_recurrent("decode", 1, int8_state)
-            tok = int(toks[req.slot])
-            done = req.finished_by(tok, self.max_model_len)
-            req.generated.append(tok)
-            tr.req_token(req.rid, now)
-            if done:
-                self.sched.finish(req, now)
 
     def _dispatch_recurrent(self, tokens, slab_ids, q_len, positions, bt,
                             temps, topks, n_real):
@@ -1387,27 +1416,25 @@ class ServingEngine:
         self._step_counter += 1
         topks = np.asarray(topks)
         cap = int(topks.max()) if topks.any() else None
-        topks_arg = jnp.asarray(topks) if topks.any() else None
         shape_key = ("recurrent",) + tuple(tokens.shape)
         first_call = shape_key not in self._step_times
-        args = (self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(slab_ids), jnp.asarray(q_len),
-                None if positions is None else jnp.asarray(positions),
-                None if bt is None else jnp.asarray(bt),
-                jnp.asarray(temps), topks_arg,
-                jnp.asarray(self._step_counter, jnp.uint32), cap)
+        tr = self.tracer
+        with tr.phase("engine.transfer", "engine"):
+            topks_arg = jnp.asarray(topks) if topks.any() else None
+            args = (self.params, jnp.asarray(tokens), self.cache,
+                    jnp.asarray(slab_ids), jnp.asarray(q_len),
+                    None if positions is None else jnp.asarray(positions),
+                    None if bt is None else jnp.asarray(bt),
+                    jnp.asarray(temps), topks_arg,
+                    jnp.asarray(self._step_counter, jnp.uint32), cap)
         if self.profiler.cost:
             self.profiler.cost_for(shape_key, self._rec_fn, *args)
-        if self.profiler.profile_dir is not None:
-            with self.profiler.step_annotation("recurrent",
-                                               self._step_counter):
-                toks, self.cache = self._rec_fn(*args)
-        else:
+        with tr.phase("engine.launch", "engine"):
             toks, self.cache = self._rec_fn(*args)
-        toks = np.asarray(toks)                      # host sync
+        with tr.phase("engine.sync", "engine"):
+            toks = np.asarray(toks)
         dt = time.perf_counter() - t0
         self._step_times.setdefault(shape_key, []).append(dt)
-        tr = self.tracer
         if tr.enabled:
             n_disp = int(np.prod(tokens.shape))
             tr.span("recurrent", "dispatch", t_start, dt, {
@@ -1469,25 +1496,24 @@ class ServingEngine:
         self._step_counter += 1
         topks = np.asarray(topks)
         cap = int(topks.max()) if topks.any() else None
-        topks_arg = jnp.asarray(topks) if topks.any() else None
         shape_key = tuple(tokens.shape)
         first_call = shape_key not in self._step_times
-        args = (self.params, jnp.asarray(tokens), self.cache,
-                jnp.asarray(positions), jnp.asarray(bt), jnp.asarray(temps),
-                topks_arg, jnp.asarray(mode_arg, jnp.int32),
-                jnp.asarray(self._step_counter, jnp.uint32), cap)
+        tr = self.tracer
+        with tr.phase("engine.transfer", "engine"):
+            topks_arg = jnp.asarray(topks) if topks.any() else None
+            args = (self.params, jnp.asarray(tokens), self.cache,
+                    jnp.asarray(positions), jnp.asarray(bt),
+                    jnp.asarray(temps), topks_arg,
+                    jnp.asarray(mode_arg, jnp.int32),
+                    jnp.asarray(self._step_counter, jnp.uint32), cap)
         if self.profiler.cost:
             self.profiler.cost_for(shape_key, step_fn, *args)
-        if self.profiler.profile_dir is not None:
-            with self.profiler.step_annotation(name, self._step_counter):
-                out = step_fn(*args)
-        else:
-            out = step_fn(*args)
-        *out, self.cache = out
-        out = [np.asarray(o) for o in out]       # host sync
+        with tr.phase("engine.launch", "engine"):
+            *out, self.cache = step_fn(*args)
+        with tr.phase("engine.sync", "engine"):
+            out = [np.asarray(o) for o in out]
         dt = time.perf_counter() - t0
         self._step_times.setdefault(shape_key, []).append(dt)
-        tr = self.tracer
         if tr.enabled:
             n_disp = int(np.prod(tokens.shape))
             tr.span(name, "dispatch", t_start, dt, {

@@ -197,6 +197,69 @@ def test_derive_latencies_skips_unfinished():
     assert lat["tpot"] == [pytest.approx(0.5)]
 
 
+def test_phases_nest_under_engine_step_in_the_ring():
+    t = [0.0]
+
+    def clock():
+        t[0] += 1.0
+        return t[0]
+    tr = Tracer(capacity=16, clock=clock, enabled=True)
+    with tr.phase("engine.step", "engine", step=3):
+        with tr.phase("engine.admit", "engine"):
+            pass
+        with tr.phase("engine.launch", "engine", args={"k": 1}):
+            pass
+    # spans land as they close: children first, each inside its parent
+    ev = list(tr.events)
+    assert [e[1] for e in ev] == ["engine.admit", "engine.launch",
+                                  "engine.step"]
+    assert all(e[0] == "X" and e[2] == "engine" for e in ev)
+    (_, _, _, s0, d0, _), (_, _, _, s1, d1, a1), (_, _, _, s, d, _) = ev
+    assert s < s0 < s0 + d0 <= s1 < s1 + d1 < s + d
+    assert a1 == {"k": 1}
+
+
+def test_phase_with_both_sinks_off_reads_no_clock():
+    calls = []
+    tr = Tracer(capacity=8, clock=lambda: calls.append(1) or 0.0,
+                enabled=False)
+    a = tr.phase("engine.step", "engine", step=0)
+    b = tr.phase("engine.plan", "engine")
+    assert a is b                    # one shared no-op context
+    with a:
+        with b:
+            pass
+    assert calls == [] and tr.n_emitted == 0 and not tr.events
+
+
+def test_phase_annotates_only_while_capturing(monkeypatch):
+    import jax
+    entered = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            entered.append((self.name, self.kw))
+
+        def __exit__(self, *exc):
+            return False
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Ann)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Ann)
+    tr = Tracer(capacity=8, clock=lambda: 0.0, enabled=False)
+    with tr.phase("engine.plan", "engine"):
+        pass
+    assert entered == []
+    tr.capturing = True
+    with tr.phase("engine.step", "engine", step=7):
+        with tr.phase("engine.plan", "engine"):
+            pass
+    assert entered == [("engine.step", {"step_num": 7}),
+                       ("engine.plan", {})]
+    assert tr.n_emitted == 0         # the ring stays off
+
+
 def test_chrome_export_schema():
     tr = Tracer(capacity=16, clock=lambda: 0.0, enabled=True)
     tr.event("pool.alloc", "pool", ts=0.001, args={"seq": 1})
@@ -273,13 +336,25 @@ def test_energy_ops_without_tokens_is_inf_not_crash():
 
 
 def test_profiler_disabled_is_inert():
-    p = Profiler()
+    tr = Tracer(capacity=8, enabled=False)
+    p = Profiler(tracer=tr)
     assert not p.enabled and p.report() is None
     with p.capture():
         pass
-    with p.step_annotation("step", 0):
+    with tr.phase("engine.step", "engine", step=0):
         pass
+    assert not tr.capturing and tr.n_emitted == 0
     assert p.cost_for(("ragged", 8, 2), None) is None
+
+
+def test_profiler_directory_is_the_capture_flag():
+    tr = Tracer(capacity=8, enabled=False)
+    p = Profiler(tracer=tr, profile_dir="/nonexistent")
+    assert tr.capturing
+    p.profile_dir = None
+    assert not tr.capturing
+    p.profile_dir = "/nonexistent"
+    assert tr.capturing and p.enabled
 
 
 # ---------------------------------------------------------------------------

@@ -440,3 +440,81 @@ def test_serve_warmup_reports_compile_separately():
     assert out["prefill_s"] > 0 and out["decode_s_per_tok"] > 0
     # steady per-token decode must not contain a multi-second jit compile
     assert out["decode_s_per_tok"] < out["compile_decode_s"]
+
+
+PHASES = ["engine.admit", "engine.plan", "engine.pack", "engine.transfer",
+          "engine.launch", "engine.sync", "engine.commit"]
+
+
+def _drain(eng, reqs):
+    for r in reqs:
+        eng.submit(r)
+    while not eng.sched.idle:
+        eng.step()
+
+
+def test_ring_shows_every_steps_phases_in_order():
+    """With the ring on, each ``step()`` is one ``engine.step`` span whose
+    children are the seven phases in order, and the dispatch's
+    ``ragged_step`` span (its args unchanged) lies between transfer and
+    sync."""
+    cfg = _cfg()
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    reqs = _workload(np.random.default_rng(3), 4, cfg.vocab_size)
+    eng = ServingEngine(cfg, params, CTX, n_slots=2, block_size=8,
+                        max_model_len=32, chunk=8, trace=True)
+    _drain(eng, reqs)
+    spans = [e for e in eng.tracer.events if e[0] == "X"]
+    steps = [e for e in spans if e[1] == "engine.step"]
+    assert len(steps) == eng.ragged_steps > 0
+    for _, _, _, t0, dur, _ in steps:
+        inner = sorted((e for e in spans if e[1] != "engine.step"
+                        and t0 <= e[3] and e[3] + e[4] <= t0 + dur),
+                       key=lambda e: e[3])
+        names = [e[1] for e in inner if e[1].startswith("engine.")]
+        assert names == PHASES
+        disp, = [e for e in inner if e[1] == "ragged_step"]
+        assert disp[2] == "dispatch"
+        assert set(disp[5]) == {"shape", "real_tokens", "padded_tokens",
+                                "compile"}
+        at = {e[1]: e for e in inner}
+        assert at["engine.transfer"][3] >= disp[3]
+        assert at["engine.sync"][3] + at["engine.sync"][4] \
+            <= disp[3] + disp[4]
+
+
+def test_caller_clock_changes_no_token_or_decision():
+    """An engine driven on its caller's clock (here a counter far from
+    the default clock's zero) serves the same tokens through the same
+    scheduler decisions, and stamps its timelines on that clock."""
+    cfg = _cfg()
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+
+    def serve(clock):
+        reqs = _workload(np.random.default_rng(4), 5, cfg.vocab_size)
+        eng = ServingEngine(cfg, params, CTX, n_slots=2, block_size=8,
+                            max_model_len=32, chunk=8, trace=True,
+                            num_blocks=7)
+        if clock is not None:
+            eng.clock = clock
+            for r in reqs:
+                r.arrival = clock()
+        _drain(eng, reqs)
+        decisions = [(e[1], e[5]) for e in eng.tracer.events
+                     if e[2] in ("sched", "pool", "cache")]
+        return eng, decisions
+
+    base, base_dec = serve(None)
+    t = [1000.0]
+
+    def clock():
+        t[0] += 0.25
+        return t[0]
+    mine, mine_dec = serve(clock)
+    assert {k: v.tolist() for k, v in mine.outputs().items()} == \
+        {k: v.tolist() for k, v in base.outputs().items()}
+    assert mine.sched.admission_log == base.sched.admission_log
+    assert mine_dec == base_dec and mine_dec
+    tls = mine.tracer.timelines.values()
+    assert all(1000.0 < tl.arrival <= tl.admit < tl.first_token <= tl.done
+               <= t[0] for tl in tls)
