@@ -37,8 +37,9 @@ from repro.kernels.residual_requant import make_residual_requant
 
 __all__ = ["int8_matmul", "quantize_act", "residual_requant",
            "flash_attention", "flash_decode", "paged_attention",
-           "ragged_attention", "attention_kv_bytes", "attn_shard_size",
-           "use_interpret", "tpu_kernels", "DEFAULT_BLOCKS", "FLASH_BLOCKS"]
+           "ragged_attention", "ragged_call_shape", "attention_kv_bytes",
+           "attn_shard_size", "use_interpret", "tpu_kernels",
+           "DEFAULT_BLOCKS", "FLASH_BLOCKS"]
 
 DEFAULT_BLOCKS = (128, 512, 512)  # (bm, bk, bn)
 FLASH_BLOCKS = (256, 512)         # (bq, bk) — q tile x kv tile
@@ -535,6 +536,26 @@ def _make_sharded_ragged(mesh: Mesh, head_entry, kv_frac_bits, scale,
         (qspec, pspec, pspec, P(None, None), P(), P(), P()), qspec)
 
 
+def ragged_call_shape(t: int, h: int, kvh: int, nbmax: int, bs: int,
+                      dk: int, dv: int, *, tq_max: Optional[int] = None,
+                      mesh: Optional[Mesh] = None,
+                      shard_axis: str = "model") -> Optional[dict]:
+    """The static shapes of the ``ragged_paged_flash`` call that
+    ``ragged_attention`` makes for a (t, h, dk) stream over pools of
+    ``bs``-row blocks with ``kvh`` KV heads and ``nbmax``-block tables:
+    the keywords of ``make_ragged_paged_flash`` after its slot count,
+    which are those of ``ragged_walk_counts``.  Head counts are per shard
+    under a multi-device ``mesh``.  None where no kernel runs — pools
+    the kernel cannot take, served by the gather oracle."""
+    if bs % 128 or dk % 128 or dv % 128:
+        return None
+    tp = (attn_shard_size(mesh, shard_axis)
+          if mesh is not None and mesh.size > 1 else 1)
+    return dict(h=h // tp, kvh=kvh // tp, nbmax=nbmax, bs=bs,
+                t_pad=_round_up(t, 8), tq=min(tq_max, t) if tq_max else t,
+                dk=dk, dv=dv)
+
+
 def ragged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                      block_tables: jax.Array, q_start: jax.Array,
                      q_len: jax.Array, kv_len: jax.Array, *,
@@ -570,8 +591,9 @@ def ragged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     dv = v_pool.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(dk)
     nkv = _resolve_kv_frac_bits(k_pool, kv_frac_bits)
-    kernel_ok = bs % 128 == 0 and dk % 128 == 0 and dv % 128 == 0
-    if not kernel_ok and not use_interpret():
+    shape = ragged_call_shape(t, h, kvh, block_tables.shape[1], bs, dk, dv,
+                              tq_max=tq_max)
+    if shape is None and not use_interpret():
         raise ValueError(
             f"ragged attention kernel needs 128-multiple block size and "
             f"head dims (got block_size={bs}, dk={dk}, dv={dv}); the "
@@ -584,7 +606,7 @@ def ragged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     if sharded:
         tp = attn_shard_size(mesh, shard_axis)
         _check_head_divisibility(kvh, tp, shard_axis)
-    if not kernel_ok:
+    if shape is None:
         # plain jnp — under a mesh GSPMD partitions it directly
         return ref.ragged_attention_ref(
             q, k_pool, v_pool, bt, qs, ql, kl,
@@ -593,13 +615,11 @@ def ragged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         call = _make_sharded_ragged(mesh, shard_axis if tp > 1 else None,
                                     kv_frac_bits, scale, tq_max)
         return call(q, k_pool, v_pool, bt, qs, ql, kl)
-    t_pad = _round_up(t, 8)
-    tq = min(tq_max, t) if tq_max else t
+    t_pad = shape["t_pad"]
     qp = _pad_to(q, 8, 0).reshape(t_pad, h * dk)
     call = make_ragged_paged_flash(
-        bt.shape[0], h, kvh, bt.shape[1], bs, t_pad, tq, dk, dv,
-        score_scale=scale * 2.0 ** (-nkv), v_scale=2.0 ** (-nkv),
-        out_dtype=q.dtype, interpret=use_interpret())
+        bt.shape[0], **shape, score_scale=scale * 2.0 ** (-nkv),
+        v_scale=2.0 ** (-nkv), out_dtype=q.dtype, interpret=use_interpret())
     out = call(qs, ql, kl, bt, qp, k_pool.reshape(nb, bs, kvh * dk),
                v_pool.reshape(nb, bs, kvh * dv)).reshape(t_pad, h, dv)
     # rows covered by no descriptor were never written by the kernel —

@@ -52,6 +52,8 @@ GOLDEN_SCHEMA = {
     "dispatched_tokens": {"kind": "counter", "type": "int"},
     "padded_tokens": {"kind": "counter", "type": "int"},
     "padding_frac": {"kind": "gauge", "type": "float", "optional": True},
+    "attn_grid_steps": {"kind": "counter", "type": "int"},
+    "attn_kv_blocks_copied": {"kind": "counter", "type": "int"},
     "speculative.spec_k": {"kind": "gauge", "type": "int"},
     "speculative.drafter": {"kind": "gauge", "type": "str"},
     "speculative.verify_steps": {"kind": "counter", "type": "int"},

@@ -46,6 +46,8 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import hwcost
 from repro.core.qmodel import QuantContext
+from repro.kernels import ops as kops
+from repro.kernels.ragged_flash import ragged_walk_counts
 from repro.launch import steps as S
 from repro.models import model as M
 from repro.models.attention import RaggedBatch
@@ -386,6 +388,7 @@ class ServingEngine:
         # no sequence feeds more than one chunk or one verify tail per
         # step: the static bound that sizes the kernel's q window
         self._tq_max = max(self.sched.chunk, kp1)
+        self._mesh = mesh           # the attention kernel's head shards
 
         # COW device copy (DESIGN §10): duplicate one pool block's rows
         # (all layers, K and V) into a fresh private block before a write
@@ -458,6 +461,10 @@ class ServingEngine:
         # draft columns — counted at dispatch time on BOTH paths
         self.dispatched_tokens = 0
         self.padded_tokens = 0
+        # the ragged attention kernel's walk, one layer's call per ragged
+        # dispatch: grid steps issued, live pool blocks copied
+        self.attn_grid_steps = 0
+        self.attn_kv_blocks_copied = 0
         self._step_times: dict[tuple, list] = {}    # shape key -> wall s
         self._t0 = time.perf_counter()
         self._skip = 0.0
@@ -551,6 +558,8 @@ class ServingEngine:
         self.recurrent_steps = 0
         self.dispatched_tokens = 0
         self.padded_tokens = 0
+        self.attn_grid_steps = 0
+        self.attn_kv_blocks_copied = 0
         self._step_times.clear()
         self._wall_s = 0.0
         if self.record:
@@ -782,6 +791,17 @@ class ServingEngine:
                 topks[i] = self._req_top_k(req)
                 fed.append(toks_i)
                 off += n
+            # the walk of the attention kernel each layer's call runs,
+            # at the shapes ops.ragged_attention builds it for
+            hd = self.cfg.resolved_head_dim
+            shape = kops.ragged_call_shape(
+                t_pad, self.cfg.n_heads, self.cfg.n_kv_heads, nbmax, bs, hd,
+                hd, tq_max=self._tq_max, mesh=self._mesh,
+                shard_axis=self.cfg.attn_shard_axis)
+            if shape is not None:
+                steps, blocks = ragged_walk_counts(kv_len, **shape)
+                self.attn_grid_steps += steps
+                self.attn_kv_blocks_copied += blocks
         out, n_acc = self._dispatch_ragged(tokens, positions, dest, bt,
                                            q_start, q_len, kv_len, temps,
                                            topks, sample_start, n_drafts,
@@ -1628,6 +1648,13 @@ class ServingEngine:
         f("padding_frac", "padded_tokens / dispatched_tokens",
           lambda: round(self.padded_tokens / self.dispatched_tokens, 4)
           if self.dispatched_tokens else None, typ=float, optional=True)
+        f("attn_grid_steps", "grid steps one layer's ragged attention call "
+          "issues, live or dead, summed over ragged dispatches",
+          lambda: self.attn_grid_steps, kind="counter", typ=int)
+        f("attn_kv_blocks_copied", "pool blocks one layer's ragged "
+          "attention call copies (the live blocks of each slot, per head "
+          "step), summed over ragged dispatches",
+          lambda: self.attn_kv_blocks_copied, kind="counter", typ=int)
         if self.spec_k:
             self._register_spec_metrics()
         for name, q in (("ttft_s", "time to first token"),

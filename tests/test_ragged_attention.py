@@ -19,6 +19,7 @@ import pytest
 
 from repro.core.qscheme import dequant, quant
 from repro.kernels import ops
+from repro.kernels.ragged_flash import _walk, ragged_walk_counts
 from repro.kernels.ref import ragged_attention_ref
 from repro.launch.mesh import make_mesh
 from repro.models.attention import _repeat_kv, chunked_attention
@@ -31,6 +32,25 @@ SMAX, DK = 256, 128
 # 5-token speculative tail rooted at context 32, and a fresh 16-token
 # prefill opening a sequence
 ITEMS = ((32, 160), (1, 131), (5, 37), (16, 16))
+
+# the kernel's walk copies P = 8 pool blocks of 128 rows per grid step
+# (``ragged_flash._walk``); these lists sit on its edges over 9-block
+# (1152-row) tables, a block count P does not divide.  EDGES: kv_len at
+# P*bs - 1, P*bs and P*bs + 1 (the last a 3-token spec tail), a sequence
+# of one live block, and one of a part block.  PADDED: padding slots
+# (q_len == kv_len == 0) between live ones, a spec tail, a prefill chunk
+WALK_SMAX = 1152
+EDGES = ((1, 1023), (1, 1024), (3, 1025), (1, 128), (2, 100))
+PADDED = ((8, 200), (0, 0), (1, 1025), (0, 0), (5, 37))
+# WIDE: a 2400-row chunk over 8 KV heads, whose q window leaves VMEM for
+# fewer than 8 KV heads a grid step — each head step copies its own
+# lanes of every pool block and owns its own q/o lanes
+WIDE_SMAX = 2560
+WIDE = ((2400, 2500), (0, 0), (1, 1025), (3, 140))
+# name -> (work-list, table rows, KV heads)
+WORKLISTS = {"mixed": (ITEMS, SMAX, 2), "walk_edges": (EDGES, WALK_SMAX, 2),
+             "padding_slots": (PADDED, WALK_SMAX, 2),
+             "wide_chunk_head_steps": (WIDE, WIDE_SMAX, 8)}
 
 
 def _build_mixed(seed, kvh, groups, kv, *, bs=128, smax=SMAX, dk=DK,
@@ -78,11 +98,28 @@ def _tol(kv):
         dict(atol=1e-4, rtol=1e-4)
 
 
+def _gather_oracle_at(rows, qf, kp, vp, bt, qs, ql, kl, nkv):
+    """The gather oracle at the given stream rows: each row restated as a
+    one-row sequence at its own absolute position.  The oracle gathers a
+    whole table per row, so this is the same per-row math at the memory
+    of ``len(rows)`` rows."""
+    qs, ql, kl = (np.asarray(a) for a in (qs, ql, kl))
+    seq = np.searchsorted(qs + ql, rows, side="right")
+    n = len(rows)
+    return ragged_attention_ref(
+        qf[rows], kp, vp, jnp.asarray(bt)[seq],
+        jnp.arange(n, dtype=jnp.int32), jnp.ones(n, jnp.int32),
+        jnp.asarray(kl[seq] - ql[seq] + rows - qs[seq] + 1, jnp.int32),
+        kv_frac_bits=nkv)
+
+
 def _check_vs_dense(out, qf, kd, vd, groups, items, kv):
     """Every work-list item against the dense chunked-attention oracle
     at its own (q_len, kv_len) — the dataflow the ragged kernel fuses."""
     off = 0
     for s, (ql, kl) in enumerate(items):
+        if ql == 0:
+            continue                                    # padding slot
         ref = chunked_attention(
             qf[None, off:off + ql], _repeat_kv(kd[s:s + 1, :kl], groups),
             _repeat_kv(vd[s:s + 1, :kl], groups), causal=True,
@@ -94,20 +131,30 @@ def _check_vs_dense(out, qf, kd, vd, groups, items, kv):
         off += ql
 
 
+@pytest.mark.parametrize("worklist", list(WORKLISTS))
 @pytest.mark.parametrize("kv", ["int8", "bf16"])
 @pytest.mark.parametrize("groups", [1, 4])
-def test_ragged_mixed_parity(groups, kv):
+def test_ragged_mixed_parity(groups, kv, worklist):
     """One pallas_call (interpret on CPU) serves the whole mixed step:
     matches both the gather oracle and the dense oracle per item."""
+    items, smax, kvh = WORKLISTS[worklist]
+    tq = max(32, max(q for q, _ in items))
     (q, kp, vp, bt, qs, ql, kl, nkv, qf, kd, vd) = \
-        _build_mixed(3, 2, groups, kv)
+        _build_mixed(3, kvh, groups, kv, smax=smax, items=items)
+    heads = _walk(**ops.ragged_call_shape(q.shape[0], kvh * groups, kvh,
+                                          smax // 128, 128, DK, DK,
+                                          tq_max=tq)).heads
+    assert (heads < kvh) == worklist.startswith("wide")
     out = ops.ragged_attention(q, kp, vp, bt, qs, ql, kl,
-                               kv_frac_bits=nkv, tq_max=32)
-    oracle = ragged_attention_ref(qf, kp, vp, bt, qs, ql, kl,
-                                  kv_frac_bits=nkv)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               kv_frac_bits=nkv, tq_max=tq)
+    # every row of a short stream; about 32 rows spread over a wide one,
+    # whose whole-stream gather would not fit the host's memory
+    t = q.shape[0]
+    rows = np.unique(np.r_[np.arange(0, t, max(1, t // 32)), t - 1])
+    oracle = _gather_oracle_at(rows, qf, kp, vp, bt, qs, ql, kl, nkv)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[rows],
                                np.asarray(oracle, np.float32), **_tol(kv))
-    _check_vs_dense(out, qf, kd, vd, groups, ITEMS, kv)
+    _check_vs_dense(out, qf, kd, vd, groups, items, kv)
 
 
 @pytest.mark.parametrize("kv", ["int8", "bf16"])
@@ -155,9 +202,11 @@ def test_ragged_small_dims_raise_off_cpu(monkeypatch):
         ops.ragged_attention(q, kp, vp, bt, qs, ql, kl, kv_frac_bits=nkv)
 
 
-@pytest.mark.parametrize("mesh_shape", [(2, 2), (1, 4)])
+@pytest.mark.parametrize("mesh_shape,kvh", [((2, 2), 4), ((1, 4), 4),
+                                            ((1, 4), 8)],
+                         ids=["2x2", "1x4", "1x4_two_kv_heads_a_shard"])
 @pytest.mark.parametrize("groups", [1, 4])
-def test_ragged_sharded_parity(groups, mesh_shape):
+def test_ragged_sharded_parity(groups, mesh_shape, kvh):
     """4-device shard_map case (DESIGN §8 composes unchanged): pool and
     stream head-sharded over 'model', descriptors replicated — must match
     the single-device dense oracle."""
@@ -165,7 +214,7 @@ def test_ragged_sharded_parity(groups, mesh_shape):
         pytest.skip("needs 4 devices (tests/conftest.py forces them)")
     mesh = make_mesh(mesh_shape, ("data", "model"))
     (q, kp, vp, bt, qs, ql, kl, nkv, qf, kd, vd) = \
-        _build_mixed(9, 4, groups, "int8")
+        _build_mixed(9, kvh, groups, "int8")
     out = ops.ragged_attention(q, kp, vp, bt, qs, ql, kl,
                                kv_frac_bits=nkv, tq_max=32, mesh=mesh)
     _check_vs_dense(out, qf, kd, vd, groups, ITEMS, "int8")
@@ -200,6 +249,31 @@ def test_ragged_padding_rows_zero():
         pad = np.asarray(out)[9:]
         assert np.all(pad == 0), "unclaimed stream rows must be zero"
         assert np.all(np.isfinite(np.asarray(out)))
+
+
+def test_ragged_walk_counts_hand_built():
+    """The kernel's walk from a hand-built descriptor set: every slot
+    takes ceil(NBmax / P) steps per head step, and each head step copies
+    ceil(kv_len / bs) blocks of each live slot, none of a padding slot's
+    (kv_len 0)."""
+    kv_len = np.asarray([0, 1, 128, 129, 1024, 1025, 0, 5000], np.int32)
+    shapes = dict(h=16, kvh=8, nbmax=40, bs=128, dk=128, dv=128)
+    # a decode stream: P = 8 blocks, all 8 KV heads in one step
+    steps, blocks = ragged_walk_counts(kv_len, t_pad=16, tq=16, **shapes)
+    assert steps == 8 * 5
+    assert blocks == 0 + 1 + 1 + 2 + 8 + 9 + 0 + 40
+    # a 4096-row chunk: its q window keeps 2 KV heads a step, so 4 head
+    # steps each walk every slot and copy its live blocks again
+    steps, blocks = ragged_walk_counts(kv_len, t_pad=4096, tq=4096,
+                                       **shapes)
+    assert (steps, blocks) == (4 * 8 * 5, 4 * 61)
+    # a table P does not divide: the last step of each slot is partial
+    steps, blocks = ragged_walk_counts([1152, 0], t_pad=8, tq=8,
+                                       **dict(shapes, nbmax=9))
+    assert (steps, blocks) == (2 * 2, 9)
+    # all padding: the walk is the grid alone
+    assert ragged_walk_counts(np.zeros(24, np.int32), t_pad=16, tq=16,
+                              **shapes) == (24 * 5, 0)
 
 
 def test_ragged_int8_requires_frac_bits():

@@ -95,6 +95,49 @@ def test_poisson_smoke_completes_and_matches_oracle():
         rep["padded_tokens"] / rep["dispatched_tokens"], 4)
 
 
+def test_attention_walk_counters_match_dispatches(monkeypatch):
+    """``attn_grid_steps`` / ``attn_kv_blocks_copied`` are the sums, over
+    the run's ragged dispatches, of the walk of the kernel each dispatch
+    ran: the steps of its grid, and per head step the ceil(kv_len / bs)
+    live blocks of each slot.  Block size and head_dim 128, so the
+    kernel (interpret mode) serves every step; padding slots and table
+    tails add steps, never blocks."""
+    from jax.experimental import pallas as pl
+    cfg = _cfg(head_dim=128)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    reqs = _workload(np.random.default_rng(4), 5, cfg.vocab_size,
+                     arrivals=True)
+    grids = {}          # stream rows -> grid of the kernel built for them
+    pallas_call = pl.pallas_call
+
+    def spy_call(kernel, **k):
+        if k.get("name") == "ragged_paged_flash":
+            grids[k["out_shape"].shape[0]] = tuple(k["grid_spec"].grid)
+        return pallas_call(kernel, **k)
+
+    monkeypatch.setattr(pl, "pallas_call", spy_call)
+    eng = ServingEngine(cfg, params, CTX, n_slots=3, block_size=128,
+                        max_model_len=384, chunk=8)
+    seen = []
+    dispatch = eng._dispatch_ragged
+
+    def spy(tokens, positions, dest, bt, q_start, q_len, kv_len, *a, **k):
+        seen.append((len(tokens), kv_len.copy()))
+        return dispatch(tokens, positions, dest, bt, q_start, q_len, kv_len,
+                        *a, **k)
+
+    eng._dispatch_ragged = spy
+    rep = eng.run(reqs)
+    assert rep["completed"] == len(reqs) and len(seen) == rep["ragged_steps"]
+    # 3-block tables fit one step with every head: a dispatch walks its 3
+    # slots in 3 grid steps
+    assert all(grids[t] == (1, 3, 1) for t, _ in seen)
+    assert rep["attn_grid_steps"] == sum(
+        int(np.prod(grids[t])) for t, _ in seen) > 0
+    assert rep["attn_kv_blocks_copied"] == sum(
+        grids[t][0] * int((-(-kl // 128)).sum()) for t, kl in seen) > 0
+
+
 def test_preemption_roundtrip_matches_oracle():
     """Undersized pool: decode growth must evict and resume (recompute),
     and the resumed requests still emit the oracle's exact tokens."""

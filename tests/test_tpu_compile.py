@@ -20,18 +20,21 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops
 from repro.kernels.flash_attention import make_flash_prefill
 from repro.kernels.int8_matmul import make_int8_matmul
-from repro.kernels.ragged_flash import make_ragged_paged_flash
+from repro.kernels.ragged_flash import (make_ragged_paged_flash,
+                                       ragged_walk_counts)
 
 H, KVH, D, BS = 16, 8, 128, 128          # qwen3_1_7b attention widths
 D_MODEL, D_FF, VOCAB = 2048, 6144, 151936
 N_SLOTS, CHUNK, NBMAX = 4, 512, 5        # chip_smoke engine settings
 T_MIN, T_MAX = 8, 520                    # its smallest / largest bucket
+BENCH_SLOTS, BENCH_NBMAX = 24, 40        # the benchmark's engine (bench/)
 
 
 @pytest.fixture(scope="module")
@@ -88,6 +91,34 @@ def test_ragged_flash_compiles(one_chip, t_pad, heads, kv_dtype):
         _sds(one_chip, (nb, BS, kvh * D), kv_dtype),
         _sds(one_chip, (nb, BS, kvh * D), kv_dtype))
     assert kernels == {"ragged_paged_flash"}
+
+
+@pytest.mark.parametrize("t_pad", [16, 536, 1024],
+                         ids=["decode", "engine_max", "prefill"])
+@pytest.mark.parametrize("kv_dtype", [jnp.int8, jnp.bfloat16],
+                         ids=["int8_kv", "bf16_kv"])
+def test_ragged_flash_bench_engine_compiles(one_chip, t_pad, kv_dtype):
+    """The benchmark engine's own shapes (24 slots, 40-block tables,
+    chunk 512): a decode-only stream, its widest stream (the 512-row
+    prefill budget plus one row a slot: 536) and a wider one.  The call
+    stays one kernel, and its walk is a few steps per slot."""
+    tq = min(CHUNK, t_pad)
+    call = make_ragged_paged_flash(
+        BENCH_SLOTS, H, KVH, BENCH_NBMAX, BS, t_pad, tq, D, D,
+        score_scale=0.088, v_scale=1 / 16, out_dtype=jnp.bfloat16)
+    nb = 1 + BENCH_SLOTS * BENCH_NBMAX
+    i32 = lambda *sh: _sds(one_chip, sh, jnp.int32)
+    kernels = _compile(
+        call, i32(BENCH_SLOTS), i32(BENCH_SLOTS), i32(BENCH_SLOTS),
+        i32(BENCH_SLOTS, BENCH_NBMAX),
+        _sds(one_chip, (t_pad, H * D), jnp.bfloat16),
+        _sds(one_chip, (nb, BS, KVH * D), kv_dtype),
+        _sds(one_chip, (nb, BS, KVH * D), kv_dtype))
+    assert kernels == {"ragged_paged_flash"}
+    steps, _ = ragged_walk_counts(
+        np.zeros(BENCH_SLOTS, np.int32), h=H, kvh=KVH, nbmax=BENCH_NBMAX,
+        bs=BS, t_pad=t_pad, tq=tq, dk=D, dv=D)
+    assert steps <= BENCH_SLOTS * -(-BENCH_NBMAX // 8)
 
 
 def test_ragged_flash_wide_chunk_compiles(one_chip):
